@@ -12,8 +12,8 @@ benchmarked shape ``decide_sharded`` prices every layout at D=8 and reports
   layout: the acceptance property that the collective term is load-bearing.
 
 An optional measured lane (``--measured``, not gated) runs the mesh
-ServeEngine on simulated host devices in a subprocess and reports real
-tokens/s next to the model.
+ServeEngine on simulated host devices in a CPU-only subprocess and reports
+its tokens/s next to the model (a CPU number, not a device one).
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ def run(shapes=((4096, 4096, 4096), (8192, 8192, 8192), (8192, 8192, 32768)),
 
 
 def run_measured(n_devices=8, requests=16, verbose=True):
-    """Real mesh ServeEngine throughput on simulated host devices (un-gated)."""
+    """Mesh ServeEngine throughput on simulated CPU devices (un-gated)."""
     import json
     import os
     import subprocess
@@ -76,6 +76,9 @@ def run_measured(n_devices=8, requests=16, verbose=True):
         "print('@@', json.dumps(eng.summary()['tokens_per_s']))\n")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    # the simulated devices are CPU devices: the child stays off any chip,
+    # which a parent that has imported JAX may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", body], env=env,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:

@@ -253,6 +253,7 @@ def lint_quant_plans(l: LCMA, shapes, hw: HardwareProfile, *,
       arrays bloat the memory traffic the decision tier priced.
     """
     from repro.analysis.stability import int8_accum_bound, max_safe_accum_depth
+    from repro.kernels import tuning
 
     findings: list[Finding] = []
     allowed = BACKEND_DTYPES.get(backend)
@@ -273,13 +274,14 @@ def lint_quant_plans(l: LCMA, shapes, hw: HardwareProfile, *,
                 f"be selected here"))
             continue
 
-        Mp = M + (-M) % l.m
-        Kp = K + (-K) % l.k
-        Np = N + (-N) % l.n
-        X, Ks, Z = Mp // l.m, Kp // l.k, Np // l.n
+        # the int8 pipeline pads each part's rows and columns to int8
+        # tiles (kernels/ops.falcon_matmul_pallas_quant); K/k stays as is
+        Ks = -(-K // l.k)
+        X = tuning.round_up(-(-M // l.m), tuning.sublane("int8"))
+        Z = tuning.round_up(-(-N // l.n), tuning.LANE)
         by = _snap_block(Ks)
-        bx = _snap_block(X)
-        bz = _snap_block(Z)
+        bx = tuning.snap_block(X, tuning.sublane("int8"))
+        bz = tuning.snap_block(Z, tuning.LANE)
 
         ok = True
         ok &= _check_div(findings, subject, "quant scale block over K/k", Ks, by)

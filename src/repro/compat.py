@@ -1,115 +1,33 @@
-"""jax version compatibility shims.
+"""Mesh helpers shared by ``core/``, ``parallel/``, ``launch/`` and ``models/``.
 
-The repo targets the modern mesh/shard_map surface (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.set_mesh`` / ``get_abstract_mesh`` /
-``AxisType``), but must also run on older releases (the CI container pins
-jax 0.4.37) where those live under ``jax.experimental.shard_map`` /
-``check_rep`` and the active mesh is the legacy ``with mesh:`` thread
-resource. Every mesh/shard_map touchpoint in ``core/``, ``parallel/``,
-``launch/``, ``models/`` and the tests goes through this module so the rest
-of the codebase is written once, against one API.
+Two conventions that plain JAX calls do not give:
+
+* ``make_mesh`` builds meshes whose axes are ``AxisType.Auto``, so GSPMD
+  places whatever the sharding rules leave open (``jax.make_mesh`` defaults
+  to ``Explicit`` axes);
+* ``get_abstract_mesh`` answers "is a mesh with axes active?" with ``None``
+  when none is (JAX returns an empty abstract mesh instead).
+
+Everything else (``jax.set_mesh``, ``jax.shard_map``, ``jax.lax.axis_size``)
+is called directly.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
-__all__ = ["make_mesh", "set_mesh", "get_abstract_mesh", "shard_map",
-           "axis_size", "HAS_NEW_SHARD_MAP", "HAS_MESH_CONTEXT_API"]
-
-HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_MESH_CONTEXT_API = (hasattr(jax.sharding, "set_mesh")
-                        and hasattr(jax.sharding, "get_abstract_mesh"))
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
+__all__ = ["make_mesh", "get_abstract_mesh"]
 
 
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates the ``axis_types`` API gap.
-
-    New jax wants explicit axis types for the context-mesh machinery; old jax
-    does not know the keyword (and has no ``AxisType`` at all). Defaulting to
-    ``AxisType.Auto`` everywhere preserves GSPMD auto-partitioning semantics.
-    """
-    kwargs = {"devices": devices} if devices is not None else {}
-    if not hasattr(jax, "make_mesh"):  # pragma: no cover - very old jax
-        from jax.experimental import mesh_utils
-        devs = mesh_utils.create_device_mesh(tuple(axis_shapes), devices=devices)
-        return jax.sharding.Mesh(devs, tuple(axis_names))
-    if _AXIS_TYPE is not None:
-        if axis_types is None:
-            axis_types = (_AXIS_TYPE.Auto,) * len(tuple(axis_names))
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=axis_types, **kwargs)
-        except TypeError:
-            pass  # jax has AxisType but make_mesh predates the keyword
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` for sharding-by-PartitionSpec.
-
-    Maps to ``jax.sharding.set_mesh`` where present; otherwise to the legacy
-    ``with mesh:`` thread-resource context (which is what pre-context-API jax
-    uses to resolve bare PartitionSpecs in ``with_sharding_constraint`` and to
-    supply the mesh for ``shard_map``).
-    """
-    if HAS_MESH_CONTEXT_API:
-        with jax.sharding.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         devices=devices)
 
 
 def get_abstract_mesh():
-    """The active mesh, normalized: returns ``None`` when no mesh is active.
-
-    (New jax returns an *empty* AbstractMesh rather than ``None``; callers
-    here always want "is there a mesh with axes to shard over?" so the empty
-    mesh is folded into ``None``.)
-    """
-    if HAS_MESH_CONTEXT_API:
-        m = jax.sharding.get_abstract_mesh()
-        if m is None or not m.axis_names:
-            return None
-        return m
-    from jax._src import mesh as _mesh_lib
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty:
+    """The active mesh, or ``None`` when no mesh with axes is active."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.axis_names:
         return None
     return m
-
-
-def axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` (new) / ``psum(1, axis)`` (old) inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, *, in_specs, out_specs, mesh=None, check_vma=True):
-    """``jax.shard_map`` across the ``check_vma``/``check_rep`` rename.
-
-    ``mesh=None`` uses the ambient mesh (``set_mesh`` above); old jax requires
-    an explicit mesh argument, so the ambient one is resolved eagerly there.
-    """
-    if HAS_NEW_SHARD_MAP:
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        try:
-            return jax.shard_map(f, check_vma=check_vma, **kwargs)
-        except TypeError:
-            return jax.shard_map(f, check_rep=check_vma, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if mesh is None:
-        mesh = get_abstract_mesh()
-        if mesh is None:
-            raise ValueError(
-                "compat.shard_map: no mesh passed and no mesh active; "
-                "wrap the call in `with compat.set_mesh(mesh):`")
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)

@@ -33,7 +33,8 @@ from typing import Callable, Sequence
 
 from . import algorithms, codegen
 from . import decision as dec
-from .hardware import HardwareProfile, get_profile, register_profile, save_profile
+from .hardware import (HardwareProfile, get_profile, interpret_kernels,
+                       register_profile, save_profile)
 from .lcma import LCMA
 
 __all__ = ["ProbeMeasurement", "CalibrationReport", "autotune", "calibrate",
@@ -231,7 +232,7 @@ def _measure_probe(M: int, K: int, N: int, l: LCMA, backend: str, dtype: str,
         # quant-pass beta: the fused Combine-A + blockwise-quantize kernel —
         # reads the fp operand, writes int8 Ã plus f32 block scales.
         from repro.kernels.quant_combine import group_combine_quant
-        qi = interpret or backend == "jnp"
+        qi = interpret or interpret_kernels()
         qcomb = jax.jit(lambda x: group_combine_quant(x, l.U, interpret=qi))
         t_qc = timer(qcomb, ap)
         by = next(d for d in range(min(128, Ks), 0, -1) if Ks % d == 0)
@@ -278,11 +279,11 @@ def measure_collective_bw(size_bytes: int = 8 << 20, reps: int = 3,
     def rs(xl):
         return jax.lax.psum_scatter(xl, "coll", tiled=True)
 
-    with compat.set_mesh(mesh):
-        f_ag = jax.jit(compat.shard_map(ag, in_specs=P("coll"),
-                                        out_specs=P(None), check_vma=False))
-        f_rs = jax.jit(compat.shard_map(rs, in_specs=P(None),
-                                        out_specs=P("coll"), check_vma=False))
+    with jax.set_mesh(mesh):
+        f_ag = jax.jit(jax.shard_map(ag, in_specs=P("coll"),
+                                     out_specs=P(None), check_vma=False))
+        f_rs = jax.jit(jax.shard_map(rs, in_specs=P(None),
+                                     out_specs=P("coll"), check_vma=False))
         t_ag = timer(f_ag, x)
         t_rs = timer(f_rs, x)
     moved = (D - 1) * n * 4                   # ring model: (D-1)/D of total

@@ -155,6 +155,15 @@ def _jnp_apply_grouped_precombined(a3, bt, l, n_logical, cfg):
     return grouped_matmul_with_precombined(a3, bt, l, n_logical, cfg)
 
 
+def _jnp_apply_quant(a2, bq, b_scales, l, n_logical, cfg):
+    # the int8 pipeline exists only as Pallas kernels; they run compiled on
+    # a TPU and interpreted on the CPU, so the jnp backend serves --quant too
+    from repro.kernels import ops
+    from .hardware import interpret_kernels
+    return ops.falcon_matmul_pallas_quant(
+        a2, bq, b_scales, l, n_logical, interpret=interpret_kernels())
+
+
 def _pallas_apply_factory(interpret: bool):
     def apply(a2, b, l, cfg):
         from repro.kernels import ops
@@ -211,10 +220,7 @@ def _ensure_builtins() -> None:
                 apply_precombined=_jnp_apply_precombined,
                 apply_grouped=_jnp_apply_grouped,
                 apply_grouped_precombined=_jnp_apply_grouped_precombined,
-                # the quant pipeline only exists as Pallas kernels; interpret
-                # mode runs them on CPU, so the jnp backend stays servable
-                # in --quant mode
-                apply_quant=_pallas_quant_factory(True),
+                apply_quant=_jnp_apply_quant,
                 description="generated pure-JAX combines (GSPMD-shardable)"),
             "pallas": Backend(
                 "pallas", _pallas_apply_factory(False),

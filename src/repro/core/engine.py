@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import algorithms, backends, workloads
+from . import algorithms, backends, hardware, workloads
 from .falcon_gemm import (FalconConfig, _lcma_apply, _lcma_apply_grouped,
                           _pad2, grouped_matmul_with_precombined,
                           matmul_with_precombined, plan, plan_batched,
@@ -247,20 +247,17 @@ def plan_weight(w: jnp.ndarray, cfg: FalconConfig | None = None,
     bq = b_scales = None
     if cfg.quantize \
             and backends.get_backend(cfg.backend).apply_quant is not None:
-        interp = cfg.backend != "pallas"
         if w.ndim == 2:
-            bq, b_scales = _quantize_weight(w, l, interpret=interp)
+            bq, b_scales = _quantize_weight(w, l)
         else:
-            per = [_quantize_weight(w[i], l, interpret=interp)
-                   for i in range(w.shape[0])]
+            per = [_quantize_weight(w[i], l) for i in range(w.shape[0])]
             bq = jnp.stack([q for q, _ in per])
             b_scales = jnp.stack([s for _, s in per])
     return PlannedWeight(w=w if keep_weight else None, bt=bt,
                          algo=l.name, k=K, n=N, bq=bq, b_scales=b_scales)
 
 
-def _quantize_weight(w: jnp.ndarray, l: LCMA, by: int | None = None,
-                     interpret: bool = True):
+def _quantize_weight(w: jnp.ndarray, l: LCMA, by: int | None = None):
     """Offline Combine-B + blockwise int8 quantization of a 2-D weight.
 
     Returns ``(B̃q int8 (R, K/k, N/n), f32 scales (R, (K/k)/by, N/n))`` —
@@ -274,7 +271,8 @@ def _quantize_weight(w: jnp.ndarray, l: LCMA, by: int | None = None,
     Y = wp.shape[0] // l.k
     if by is None:
         by = next(d for d in range(min(128, Y), 0, -1) if Y % d == 0)
-    return quantize_b_blockwise(wp, l.V, by=by, interpret=interpret)
+    return quantize_b_blockwise(wp, l.V, by=by,
+                                interpret=hardware.interpret_kernels())
 
 
 _DEFAULT_PRECOMBINE_PATTERNS = (

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import logging
 import threading
 
@@ -31,7 +32,7 @@ import numpy as np
 from repro import compat
 
 from . import algorithms, backends, codegen, decision as dec, plan_cache
-from .hardware import HardwareProfile, get_profile
+from .hardware import HardwareProfile, device_profile_name, get_profile
 from .lcma import LCMA
 
 log = logging.getLogger(__name__)
@@ -47,7 +48,8 @@ class FalconConfig:
     """Trace-time policy for FalconGEMM dispatch."""
 
     enabled: bool = True
-    hardware: str = "tpu_v5e"
+    # Profile name; None prices the attached chip (``device_profile_name``).
+    hardware: str | None = None
     backend: str = "jnp"             # any name in core.backends registry
     fused: bool = True
     mode: str = "auto"               # "auto" | "gemm" | explicit scheme name
@@ -78,7 +80,7 @@ class FalconConfig:
 
     @property
     def profile(self) -> HardwareProfile:
-        return get_profile(self.hardware)
+        return get_profile(self.hardware or device_profile_name())
 
     def candidate_schemes(self) -> list[LCMA]:
         if self.candidates is not None:
@@ -352,6 +354,18 @@ def _pad3(x: jnp.ndarray, d0: int, d1: int) -> jnp.ndarray:
     return x
 
 
+def _shared_b_products(at: jnp.ndarray, bt: jnp.ndarray) -> jnp.ndarray:
+    """(G, R, X, Y) x shared (R, Y, Z) -> f32 (G, R, X, Z), one dot over r.
+
+    Written as a ``dot_general`` with the activation first: ``jnp.einsum``
+    puts the shared operand first for this contraction, and XLA:CPU has no
+    bf16 x bf16 -> f32 kernel for that operand order.
+    """
+    h = jax.lax.dot_general(at, bt, (((3,), (1,)), ((1,), (0,))),
+                            preferred_element_type=jnp.float32)
+    return h.transpose(1, 0, 2, 3)
+
+
 def grouped_matmul_generated(a3: jnp.ndarray, b: jnp.ndarray, l: LCMA,
                              cfg: FalconConfig) -> jnp.ndarray:
     """Grouped LCMA via the generated pure-JAX combines (the jnp backend).
@@ -369,8 +383,7 @@ def grouped_matmul_generated(a3: jnp.ndarray, b: jnp.ndarray, l: LCMA,
     if b.ndim == 2:
         N = b.shape[1]
         bt = gen.combine_b(_pad2(b, l.k, l.n))             # hoisted: once
-        h = jnp.einsum("grxy,ryz->grxz", at, bt,
-                       preferred_element_type=jnp.float32)
+        h = _shared_b_products(at, bt)
     else:
         N = b.shape[2]
         bt = jax.vmap(gen.combine_b)(_pad3(b, l.k, l.n))   # (G, R, Ks, Ns)
@@ -402,8 +415,7 @@ def grouped_matmul_with_precombined(a3: jnp.ndarray, bt: jnp.ndarray, l: LCMA,
             f"B̃ {tuple(bt.shape)} for scheme {l.name} {l.key}")
     at = jax.vmap(gen.combine_a)(ap)
     if bt.ndim == 3:
-        h = jnp.einsum("grxy,ryz->grxz", at, bt.astype(at.dtype),
-                       preferred_element_type=jnp.float32)
+        h = _shared_b_products(at, bt.astype(at.dtype))
     else:
         if bt.shape[0] != G:
             raise ValueError(
@@ -500,7 +512,7 @@ def _falcon_dense_shardmap(x: jnp.ndarray, w: jnp.ndarray,
     # flatten tokens so the (possibly small) batch dim times seq shards over
     # the full mesh: (B, S, K) -> (B*S, K) with B*S % n_devices == 0
     xspec = P(axes, None)
-    out = compat.shard_map(
+    out = jax.shard_map(
         body, in_specs=(xspec, P(None, None)),
         out_specs=xspec, check_vma=False)(x.reshape(T, K), w)
     return out.reshape(*lead, N)
@@ -510,8 +522,14 @@ def _falcon_dense_shardmap(x: jnp.ndarray, w: jnp.ndarray,
 # Offline Combine B (static weights, serving path)
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnums=1)
 def precombine_weights(w: jnp.ndarray, l: LCMA) -> jnp.ndarray:
-    """Offline Combine B of a static weight matrix: (K, N) -> (R, K/k, N/n)."""
+    """Offline Combine B of a static weight matrix: (K, N) -> (R, K/k, N/n).
+
+    Compiled, so B̃ is written straight into its output: run op by op, the
+    slices and partial sums of a full-width layer stack would briefly hold
+    about three times B̃ in device memory.
+    """
     gen = codegen.generate(l, codegen.CodegenOptions(precombined_b=True))
     return gen.combine_b(_pad2(w, l.k, l.n))
 

@@ -17,7 +17,8 @@ import time
 
 __all__ = ["HardwareProfile", "TPU_V5E", "TPU_V5E_POD", "CPU_HOST", "get_profile",
            "calibrate_cpu", "register_profile", "profile_dir", "profile_path",
-           "save_profile", "load_profile", "ENV_PROFILE_DIR"]
+           "save_profile", "load_profile", "ENV_PROFILE_DIR", "DEVICE_PROFILES",
+           "device_profile_name", "interpret_kernels"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,6 +98,41 @@ CPU_HOST = HardwareProfile(
 )
 
 _PROFILES = {p.name: p for p in (TPU_V5E, TPU_V5E_POD, CPU_HOST)}
+
+# ``device_kind`` as JAX reports it -> the profile that prices that chip. A
+# v5e reports "TPU v5 lite". A TPU that is not listed is an error: pricing it
+# with another chip's peaks would pick its schemes on wrong numbers.
+DEVICE_PROFILES = {"TPU v5 lite": "tpu_v5e"}
+
+
+def device_profile_name(device=None) -> str:
+    """Name of the profile for ``device`` (default: ``jax.devices()[0]``).
+
+    A TPU resolves by ``device_kind`` through :data:`DEVICE_PROFILES`; an
+    unlisted kind raises ``KeyError``. Any other platform -- the CPU that
+    tests and rehearsals run on -- prices the analytic ``tpu_v5e`` profile,
+    the chip these plans are made for.
+    """
+    import jax
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return TPU_V5E.name
+    try:
+        return DEVICE_PROFILES[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no hardware profile for TPU device_kind "
+            f"{device.device_kind!r}; known: {sorted(DEVICE_PROFILES)}") from None
+
+
+def interpret_kernels() -> bool:
+    """Whether Pallas kernels must run in interpret mode: only on the CPU.
+
+    The one place that decides it, so no path that reaches a TPU interprets.
+    """
+    import jax
+    return jax.default_backend() == "cpu"
+
 
 # Calibrated profiles written by ``repro.tools.tune`` live here; set the env
 # var to relocate (CI, multi-host). Looked up lazily by ``get_profile``.

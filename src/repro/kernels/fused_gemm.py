@@ -26,13 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are a no-op under interpret mode / CPU testing
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _fused_kernel(at_ref, bt_ref, out_ref, acc_ref, *, w, grid_y):
@@ -84,11 +78,6 @@ def fused_gemm_combine_h(at: jnp.ndarray, bt: jnp.ndarray, w: np.ndarray,
     grid = (X // bx, Z // bz, Y // by)
 
     kernel = functools.partial(_fused_kernel, w=w, grid_y=grid[2])
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover - TPU-only path
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
     fn = pl.pallas_call(
         kernel,
         grid=grid,
@@ -98,10 +87,10 @@ def fused_gemm_combine_h(at: jnp.ndarray, bt: jnp.ndarray, w: np.ndarray,
         ],
         out_specs=pl.BlockSpec((m, n, bx, bz), lambda x, z, y: (0, 0, x, z)),
         out_shape=jax.ShapeDtypeStruct((m, n, X, Z), out_dtype),
-        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)] if _HAS_PLTPU
-        else [pl.MemorySpace.ANY((R, bx, bz), jnp.float32)],  # pragma: no cover
+        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )
     return fn(at, bt)
 
@@ -181,12 +170,6 @@ def batched_fused_gemm_combine_h(at: jnp.ndarray, bt: jnp.ndarray,
 
     kernel = functools.partial(_batched_fused_kernel, w=w, grid_y=grid[3],
                                bt_batched=bt_batched)
-    kwargs = {}
-    if _HAS_PLTPU and not interpret:  # pragma: no cover - TPU-only path
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")
-        )
     fn = pl.pallas_call(
         kernel,
         grid=grid,
@@ -197,10 +180,11 @@ def batched_fused_gemm_combine_h(at: jnp.ndarray, bt: jnp.ndarray,
         out_specs=pl.BlockSpec((1, m, n, bx, bz),
                                lambda g, x, z, y: (g, 0, 0, x, z)),
         out_shape=jax.ShapeDtypeStruct((G, m, n, X, Z), out_dtype),
-        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)] if _HAS_PLTPU
-        else [pl.MemorySpace.ANY((R, bx, bz), jnp.float32)],  # pragma: no cover
+        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )
     return fn(at, bt)
 
@@ -241,8 +225,9 @@ def tiled_matmul(a: jnp.ndarray, b: jnp.ndarray, *, block: tuple[int, int, int] 
         ],
         out_specs=pl.BlockSpec((bx, bz), lambda x, z, y: (x, z)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bx, bz), jnp.float32)] if _HAS_PLTPU
-        else [pl.MemorySpace.ANY((bx, bz), jnp.float32)],  # pragma: no cover
+        scratch_shapes=[pltpu.VMEM((bx, bz), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )
     return fn(a, b)

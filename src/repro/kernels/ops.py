@@ -2,7 +2,9 @@
 
 ``falcon_matmul_pallas`` is the full on-TPU LCMA pipeline:
   Group Combine A  ->  Group Combine B  ->  fused GEMM + Group Combine H
-with all padding/unpadding handled here so kernels see exact tiles.
+with all padding/unpadding handled here so kernels see exact tiles: every
+part of A and B is zero-padded up to whole TPU tiles (``tuning.part_dims``),
+so every block the planner picks is tile-aligned.
 """
 from __future__ import annotations
 
@@ -15,10 +17,36 @@ from repro.core.lcma import LCMA
 # one padding definition shared with the generated-jnp pipeline — the two
 # execution paths must pad identically or their outputs diverge at the edges
 from repro.core.falcon_gemm import _pad2, _pad3
+from . import tuning
 from .fused_gemm import (batched_fused_gemm_combine_h, fused_gemm_combine_h,
                          tiled_matmul)
 from .group_combine import batched_group_combine, group_combine
 from .quant_combine import fused_gemm_combine_h_quant, group_combine_quant
+
+
+def _pad_last2(x: jnp.ndarray, s1: int, s2: int) -> jnp.ndarray:
+    p1, p2 = s1 - x.shape[-2], s2 - x.shape[-1]
+    if not (p1 or p2):
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, p1), (0, p2)])
+
+
+def _assemble(cp: jnp.ndarray, xs: int, zs: int, M: int,
+              N: int) -> jnp.ndarray:
+    """C parts (..., m, n, X, Z) -> (..., M, N): unpad each part, then tile."""
+    cp = cp[..., :xs, :zs]
+    *lead, m, n, X, Z = cp.shape
+    nl = len(lead)
+    perm = tuple(range(nl)) + (nl, nl + 2, nl + 1, nl + 3)
+    c = cp.transpose(perm).reshape(*lead, m * X, n * Z)
+    return c[..., :M, :N]
+
+
+def _check_precombined(where: str, K: int, bt, l: LCMA) -> None:
+    if -(-K // l.k) != bt.shape[-2]:
+        raise ValueError(
+            f"{where}: activation K={K} (grid k={l.k}) does not match "
+            f"precombined B̃ {tuple(bt.shape)} for scheme {l.name} {l.key}")
 
 
 @partial(jax.jit, static_argnames=("l", "block_combine", "block_gemm", "interpret"))
@@ -32,19 +60,14 @@ def falcon_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, l: LCMA,
     if K != K2:
         raise ValueError(f"falcon_matmul_pallas: contracting dims differ: "
                          f"{a.shape} @ {b.shape}")
-    # Pad to grid multiples. The K pads of A and B coincide (both are
-    # (-K) % l.k), so the combined operands stay K-consistent. Tile sizes are
-    # chosen on the padded submatrix sizes by the resource planner unless
-    # pinned by the caller.
-    ap = _pad2(a, l.m, l.k)
-    bp = _pad2(b, l.k, l.n)
+    X, Y, Z = tuning.part_dims(l, M, K, N, a.dtype)
+    ap = tuning.pad_parts(_pad2(a, l.m, l.k), (l.m, l.k), (X, Y))
+    bp = tuning.pad_parts(_pad2(b, l.k, l.n), (l.k, l.n), (Y, Z))
     at = group_combine(ap, l.U, block=block_combine, interpret=interpret)
     bt = group_combine(bp, l.V, block=block_combine, interpret=interpret)
     cp = fused_gemm_combine_h(at, bt, l.W, block=block_gemm,
                               out_dtype=a.dtype, interpret=interpret)
-    m, n, X, Z = cp.shape
-    c = cp.transpose(0, 2, 1, 3).reshape(m * X, n * Z)
-    return c[:M, :N]
+    return _assemble(cp, -(-M // l.m), -(-N // l.n), M, N)
 
 
 @partial(jax.jit, static_argnames=("l", "n_logical", "block_combine",
@@ -63,18 +86,14 @@ def falcon_matmul_pallas_precombined(
     offline by either path are interchangeable.
     """
     M, K = a.shape
-    ap = _pad2(a, l.m, l.k)
-    if ap.shape[1] // l.k != bt.shape[1]:
-        raise ValueError(
-            f"falcon_matmul_pallas_precombined: activation K={K} (padded "
-            f"{ap.shape[1]}, grid k={l.k}) does not match precombined "
-            f"B̃ {tuple(bt.shape)} for scheme {l.name} {l.key}")
+    _check_precombined("falcon_matmul_pallas_precombined", K, bt, l)
+    ys, zs = bt.shape[-2:]
+    X, Y, Z = tuning.part_dims(l, M, ys * l.k, zs * l.n, a.dtype)
+    ap = tuning.pad_parts(_pad2(a, l.m, l.k), (l.m, l.k), (X, Y))
     at = group_combine(ap, l.U, block=block_combine, interpret=interpret)
-    cp = fused_gemm_combine_h(at, bt, l.W, block=block_gemm,
+    cp = fused_gemm_combine_h(at, _pad_last2(bt, Y, Z), l.W, block=block_gemm,
                               out_dtype=a.dtype, interpret=interpret)
-    m, n, X, Z = cp.shape
-    c = cp.transpose(0, 2, 1, 3).reshape(m * X, n * Z)
-    return c[:M, :n_logical]
+    return _assemble(cp, -(-M // l.m), zs, M, n_logical)
 
 
 @partial(jax.jit, static_argnames=("l", "n_logical", "block_combine",
@@ -91,35 +110,31 @@ def falcon_matmul_pallas_quant(
     A, int8 Ã plus per-(row, K-block) f32 scales out), then the fused int8
     GEMM + dequantizing Combine H. ``bq``/``b_scales`` come from
     ``quantize_b_blockwise`` (the PlannedWeight quant buffers); the A-side
-    scale block is forced to B's so the two block-scale grids line up.
+    scale block is forced to B's so the two block-scale grids line up. The
+    rows and columns of each part are padded to int8 tiles here; the
+    combined K is already a whole number of scale blocks.
     """
     M, K = a.shape
-    ap = _pad2(a, l.m, l.k)
-    Y = bq.shape[1]
-    if ap.shape[1] // l.k != Y:
-        raise ValueError(
-            f"falcon_matmul_pallas_quant: activation K={K} (padded "
-            f"{ap.shape[1]}, grid k={l.k}) does not match quantized "
-            f"B̃q {tuple(bq.shape)} for scheme {l.name} {l.key}")
-    by = Y // b_scales.shape[1]
-    bcx = block_combine[0] if block_combine else 128
+    _check_precombined("falcon_matmul_pallas_quant", K, bq, l)
+    ys, zs = bq.shape[1:]
+    by = ys // b_scales.shape[1]
+    X = tuning.round_up(-(-M // l.m), tuning.sublane(jnp.int8))
+    Z = tuning.round_up(zs, tuning.LANE)
+    ap = tuning.pad_parts(_pad2(a, l.m, l.k), (l.m, l.k), (X, ys))
+    bcx = block_combine[0] if block_combine else \
+        tuning.snap_block(X, tuning.sublane(jnp.int8))
     at, a_scales = group_combine_quant(ap, l.U, block=(bcx, by),
                                        interpret=interpret)
-    X = ap.shape[0] // l.m
-    Z = bq.shape[2]
     if block_gemm is not None:
         bx, bz = block_gemm[0], block_gemm[1]
     else:
-        # the fused kernel asserts exact divisibility; snap its defaults to
-        # the largest divisors <= 128 (same idiom as group_combine_quant)
-        bx = next(d for d in range(min(128, X), 0, -1) if X % d == 0)
-        bz = next(d for d in range(min(128, Z), 0, -1) if Z % d == 0)
-    cp = fused_gemm_combine_h_quant(at, a_scales, bq, b_scales, l.W,
-                                    block=(bx, bz, by), out_dtype=a.dtype,
+        bx = tuning.snap_block(X, tuning.sublane(jnp.int8))
+        bz = tuning.snap_block(Z, tuning.LANE)
+    cp = fused_gemm_combine_h_quant(at, a_scales, _pad_last2(bq, ys, Z),
+                                    _pad_last2(b_scales, b_scales.shape[1], Z),
+                                    l.W, block=(bx, bz, by), out_dtype=a.dtype,
                                     interpret=interpret)
-    m, n, Xc, Zc = cp.shape
-    c = cp.transpose(0, 2, 1, 3).reshape(m * Xc, n * Zc)
-    return c[:M, :n_logical]
+    return _assemble(cp, -(-M // l.m), zs, M, n_logical)
 
 
 @partial(jax.jit, static_argnames=("l", "block_combine", "block_gemm", "interpret"))
@@ -143,20 +158,20 @@ def falcon_grouped_matmul_pallas(a3: jnp.ndarray, b: jnp.ndarray, l: LCMA,
     if not shared and b.shape[0] != G:
         raise ValueError(f"falcon_grouped_matmul_pallas: group sizes differ: "
                          f"{a3.shape} @ {b.shape}")
-    ap = _pad3(a3, l.m, l.k)
+    X, Y, Z = tuning.part_dims(l, M, K, N, a3.dtype)
+    ap = tuning.pad_parts(_pad3(a3, l.m, l.k), (l.m, l.k), (X, Y))
     at = batched_group_combine(ap, l.U, block=block_combine,
                                interpret=interpret)
     if shared:
-        bt = group_combine(_pad2(b, l.k, l.n), l.V, block=block_combine,
-                           interpret=interpret)
+        bp = tuning.pad_parts(_pad2(b, l.k, l.n), (l.k, l.n), (Y, Z))
+        bt = group_combine(bp, l.V, block=block_combine, interpret=interpret)
     else:
-        bt = batched_group_combine(_pad3(b, l.k, l.n), l.V,
-                                   block=block_combine, interpret=interpret)
+        bp = tuning.pad_parts(_pad3(b, l.k, l.n), (l.k, l.n), (Y, Z))
+        bt = batched_group_combine(bp, l.V, block=block_combine,
+                                   interpret=interpret)
     cp = batched_fused_gemm_combine_h(at, bt, l.W, block=block_gemm,
                                       out_dtype=a3.dtype, interpret=interpret)
-    g, m, n, X, Z = cp.shape
-    c = cp.transpose(0, 1, 3, 2, 4).reshape(G, m * X, n * Z)
-    return c[:, :M, :N]
+    return _assemble(cp, -(-M // l.m), -(-N // l.n), M, N)
 
 
 @partial(jax.jit, static_argnames=("l", "n_logical", "block_combine",
@@ -173,23 +188,20 @@ def falcon_grouped_matmul_pallas_precombined(
     weights (MoE experts precombined offline). Combine B never runs.
     """
     G, M, K = a3.shape
-    ap = _pad3(a3, l.m, l.k)
-    if ap.shape[2] // l.k != bt.shape[-2]:
-        raise ValueError(
-            f"falcon_grouped_matmul_pallas_precombined: activation K={K} "
-            f"(padded {ap.shape[2]}, grid k={l.k}) does not match precombined "
-            f"B̃ {tuple(bt.shape)} for scheme {l.name} {l.key}")
+    _check_precombined("falcon_grouped_matmul_pallas_precombined", K, bt, l)
     if bt.ndim == 4 and bt.shape[0] != G:
         raise ValueError(
             f"falcon_grouped_matmul_pallas_precombined: group sizes differ: "
             f"{a3.shape} vs B̃ {tuple(bt.shape)}")
+    ys, zs = bt.shape[-2:]
+    X, Y, Z = tuning.part_dims(l, M, ys * l.k, zs * l.n, a3.dtype)
+    ap = tuning.pad_parts(_pad3(a3, l.m, l.k), (l.m, l.k), (X, Y))
     at = batched_group_combine(ap, l.U, block=block_combine,
                                interpret=interpret)
-    cp = batched_fused_gemm_combine_h(at, bt, l.W, block=block_gemm,
-                                      out_dtype=a3.dtype, interpret=interpret)
-    g, m, n, X, Z = cp.shape
-    c = cp.transpose(0, 1, 3, 2, 4).reshape(G, m * X, n * Z)
-    return c[:, :M, :n_logical]
+    cp = batched_fused_gemm_combine_h(at, _pad_last2(bt, Y, Z), l.W,
+                                      block=block_gemm, out_dtype=a3.dtype,
+                                      interpret=interpret)
+    return _assemble(cp, -(-M // l.m), zs, M, n_logical)
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
@@ -199,8 +211,8 @@ def matmul_pallas(a: jnp.ndarray, b: jnp.ndarray,
     """Standard tiled-matmul kernel with padding."""
     M, K = a.shape
     _, N = b.shape
-    ap = _pad2(a, 8, 128)
-    bp = _pad2(b, 128, 128)
+    ap = _pad2(a, tuning.sublane(a.dtype), tuning.LANE)
+    bp = _pad2(b, tuning.LANE, tuning.LANE)
     if ap.shape[1] != bp.shape[0]:
         kp = max(ap.shape[1], bp.shape[0])
         ap = jnp.pad(ap, ((0, 0), (0, kp - ap.shape[1])))

@@ -23,13 +23,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+# Inside the kernels the block scales are laid out with the scaled dim last
+# but one beside a unit dim -- A's as (R, Yb, X, 1), B's as (R, Yb, 1, Z) --
+# so each block's last two dims are (rows, 1) / (1, cols): whole-array dims
+# or tile multiples, as Mosaic requires. The public layouts stay (R, X, Yb)
+# and (R, Yb, Z).
 
 
 def _quant_combine_kernel(*refs, coeff, nin):
@@ -53,20 +53,25 @@ def _quant_combine_kernel(*refs, coeff, nin):
         s = jnp.maximum(s, 1e-12)
         q = jnp.clip(jnp.round(acc / s), -127, 127).astype(jnp.int8)
         q_ref[r, :, :] = q
-        s_ref[r, :, :] = s
+        s_ref[r, 0, :, :] = s
 
 
 def group_combine_quant(x: jnp.ndarray, coeff: np.ndarray, *,
                         block: tuple[int, int] = (128, 128),
                         interpret: bool = False):
-    """x: (d1*X, d2*Y) -> (q int8 (R, X, Y), scales f32 (R, X, Y/by))."""
+    """x: (d1*X, d2*Y) -> (q int8 (R, X, Y), scales f32 (R, X, Y/by)).
+
+    ``block`` (bx, by) must tile the part (X, Y); ``by`` is the K-block the
+    scales cover. Padding is the caller's (`repro.kernels.ops`).
+    """
     R, d1, d2 = coeff.shape
     M, K = x.shape
     assert M % d1 == 0 and K % d2 == 0
     X, Y = M // d1, K // d2
-    bx, by = block
-    bx = min(bx, X) if X % min(bx, X) == 0 else [d for d in range(min(bx, X), 0, -1) if X % d == 0][0]
-    by = min(by, Y) if Y % min(by, Y) == 0 else [d for d in range(min(by, Y), 0, -1) if Y % d == 0][0]
+    bx, by = min(block[0], X), min(block[1], Y)
+    if X % bx or Y % by:
+        raise ValueError(f"group_combine_quant: block {(bx, by)} does not "
+                         f"tile the part {(X, Y)}")
     grid = (X // bx, Y // by)
     in_specs = []
     for i in range(d1):
@@ -77,15 +82,16 @@ def group_combine_quant(x: jnp.ndarray, coeff: np.ndarray, *,
                     lambda gx, gy, i=i, l=l: (i * (X // bx) + gx, l * (Y // by) + gy))))
     out_specs = [
         pl.BlockSpec((R, bx, by), lambda gx, gy: (0, gx, gy)),
-        pl.BlockSpec((R, bx, 1), lambda gx, gy: (0, gx, gy)),
+        pl.BlockSpec((R, 1, bx, 1), lambda gx, gy: (0, gy, gx, 0)),
     ]
     kernel = functools.partial(_quant_combine_kernel, coeff=coeff, nin=d1 * d2)
     fn = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((R, X, Y), jnp.int8),
-                   jax.ShapeDtypeStruct((R, X, Y // by), jnp.float32)],
+                   jax.ShapeDtypeStruct((R, Y // by, X, 1), jnp.float32)],
         interpret=interpret)
-    return fn(*([x] * (d1 * d2)))
+    q, s = fn(*([x] * (d1 * d2)))
+    return q, s[..., 0].transpose(0, 2, 1)
 
 
 def _fused_quant_kernel(aq_ref, as_ref, bq_ref, bs_ref, out_ref, acc_ref, *,
@@ -103,7 +109,7 @@ def _fused_quant_kernel(aq_ref, as_ref, bq_ref, bs_ref, out_ref, acc_ref, *,
         part = jax.lax.dot_general(
             aq_ref[r], bq_ref[r], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32).astype(jnp.float32)
-        acc_ref[r, :, :] += part * as_ref[r] * bs_ref[r]
+        acc_ref[r, :, :] += part * as_ref[r, 0] * bs_ref[r, 0]
 
     @pl.when(y == grid_y - 1)
     def _combine_h():
@@ -153,16 +159,19 @@ def fused_gemm_combine_h_quant(aq, a_scales, bq, b_scales, w: np.ndarray, *,
         kernel, grid=grid,
         in_specs=[
             pl.BlockSpec((R, bx, by), lambda x, z, y: (0, x, y)),
-            pl.BlockSpec((R, bx, 1), lambda x, z, y: (0, x, y)),
+            pl.BlockSpec((R, 1, bx, 1), lambda x, z, y: (0, y, x, 0)),
             pl.BlockSpec((R, by, bz), lambda x, z, y: (0, y, z)),
-            pl.BlockSpec((R, 1, bz), lambda x, z, y: (0, y, z)),
+            pl.BlockSpec((R, 1, 1, bz), lambda x, z, y: (0, y, 0, z)),
         ],
         out_specs=pl.BlockSpec((m, n, bx, bz), lambda x, z, y: (0, 0, x, z)),
         out_shape=jax.ShapeDtypeStruct((m, n, X, Z), out_dtype),
-        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)] if _HAS_PLTPU
-        else [],  # pragma: no cover
+        scratch_shapes=[pltpu.VMEM((R, bx, bz), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret)
-    return fn(aq, a_scales, bq, b_scales)
+    a_s = jnp.transpose(a_scales, (0, 2, 1))[..., None]      # (R, Yb, X, 1)
+    b_s = b_scales[:, :, None, :]                             # (R, Yb, 1, Z)
+    return fn(aq, a_s, bq, b_s)
 
 
 def quantize_b_blockwise(b: jnp.ndarray, coeff: np.ndarray, by: int = 128,
@@ -170,11 +179,18 @@ def quantize_b_blockwise(b: jnp.ndarray, coeff: np.ndarray, by: int = 128,
     """Offline Combine-B + quantization for static weights (serving path).
 
     Returns (bq int8 (R, Y, Z), b_scales (R, Yb, Z)) with per-(K-block, col)
-    scales, matching ``fused_gemm_combine_h_quant``.
+    scales, matching ``fused_gemm_combine_h_quant``. ``b`` is (k*Y, n*Z);
+    each part is padded to whole tiles for the combine and cut back after.
     """
+    from . import tuning
     from .group_combine import group_combine
-    bt = group_combine(b, coeff, interpret=interpret).astype(jnp.float32)
-    R, Y, Z = bt.shape
+    R, k, n = coeff.shape
+    Y, Z = b.shape[0] // k, b.shape[1] // n
+    parts = (tuning.round_up(Y, tuning.sublane(b.dtype)),
+             tuning.round_up(Z, tuning.LANE))
+    bp = tuning.pad_parts(b, (k, n), parts)
+    bt = group_combine(bp, coeff, interpret=interpret)[:, :Y, :Z]
+    bt = bt.astype(jnp.float32)
     assert Y % by == 0
     btb = bt.reshape(R, Y // by, by, Z)
     s = jnp.maximum(jnp.max(jnp.abs(btb), axis=2) / 127.0, 1e-12)  # (R, Yb, Z)

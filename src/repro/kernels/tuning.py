@@ -16,14 +16,64 @@ import jax.numpy as jnp
 # Pallas pipeline double-buffers in/out blocks, which the estimates include.
 VMEM_BUDGET = 12 << 20
 MXU = 128
+LANE = 128
 
 
-def _align_candidates(dim: int, mxu: int = MXU) -> list[int]:
-    """Block-size candidates for a dimension: MXU multiples, then divisors."""
-    cands = [c for c in (512, 384, 256, 128) if dim % c == 0]
-    if not cands:
-        cands = [d for d in range(min(dim, 512), 0, -1) if dim % d == 0]
-    return cands
+def sublane(dtype) -> int:
+    """Rows of one native TPU tile of ``dtype``: 8 (32-bit), 16 (bf16), 32 (int8)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def round_up(dim: int, align: int) -> int:
+    return -(-dim // align) * align
+
+
+def part_dims(l, M: int, K: int, N: int, dtype) -> tuple[int, int, int]:
+    """Part sizes ``(X, Y, Z)`` of the padded problem the Pallas kernels run.
+
+    A splits into m x k parts of X x Y, B into k x n parts of Y x Z. Each part
+    is padded up to whole TPU tiles -- X to the dtype's sublane rows, Y and Z
+    to 128 lanes (Y is a lane dim of Ã and a row dim of B̃) -- because Mosaic
+    takes a block only if its last two dims are multiples of (sublane, 128)
+    or span the whole array, and a part never spans the whole array.
+    """
+    return (round_up(-(-M // l.m), sublane(dtype)),
+            round_up(-(-K // l.k), LANE), round_up(-(-N // l.n), LANE))
+
+
+def pad_parts(x: jnp.ndarray, grid: tuple[int, int],
+               parts: tuple[int, int]) -> jnp.ndarray:
+    """Zero-pad each part of ``x`` at its end, keeping the part in place.
+
+    ``x`` is (..., d1*a, d2*b), a d1 x d2 grid of (a, b) parts; the result
+    is (..., d1*s1, d2*s2). A part padded this way combines to the part
+    padded the same way, so an offline B̃ needs only its last two dims
+    padded to match.
+    """
+    (d1, d2), (s1, s2) = grid, parts
+    *lead, r, c = x.shape
+    a, b = r // d1, c // d2
+    if (a, b) == (s1, s2):
+        return x
+    x = x.reshape(*lead, d1, a, d2, b)
+    pad = [(0, 0)] * len(lead) + [(0, 0), (0, s1 - a), (0, 0), (0, s2 - b)]
+    return jnp.pad(x, pad).reshape(*lead, d1 * s1, d2 * s2)
+
+
+def snap_block(dim: int, align: int, cap: int = 128) -> int:
+    """Largest multiple of ``align`` that divides ``dim`` and is <= ``cap``."""
+    return max(c for c in range(align, max(min(dim, cap), align) + 1, align)
+               if dim % c == 0)
+
+
+def _align_candidates(dim: int, align: int = MXU) -> list[int]:
+    """Block sizes for a dimension: multiples of ``align`` dividing it, <= 512.
+
+    Falls back to every divisor only for a dimension that is not a multiple
+    of ``align`` (never one ``part_dims`` produced)."""
+    cands = [c for c in range(min(dim, 512) // align * align, 0, -align)
+             if dim % c == 0]
+    return cands or _all_divisors(dim)
 
 
 def _all_divisors(dim: int) -> list[int]:
@@ -38,10 +88,11 @@ def combine_vmem(bx: int, by: int, R: int, nparts: int, itemsize: int) -> int:
 
 def plan_combine_blocks(X: int, Y: int, R: int, nparts: int, dtype,
                         budget: int = VMEM_BUDGET) -> tuple[int, int]:
+    """Pick (bx, by) for a combine over parts of X x Y: rows, then lanes."""
     it = jnp.dtype(dtype).itemsize
     best = None
-    for bx in _align_candidates(X):
-        for by in _align_candidates(Y):
+    for bx in _align_candidates(X, sublane(dtype)):
+        for by in _align_candidates(Y, LANE):
             if combine_vmem(bx, by, R, nparts, it) <= budget:
                 cand = (bx, by)
                 if best is None or bx * by > best[0] * best[1]:
@@ -78,10 +129,8 @@ def block_plans(l, M: int, K: int, N: int, dtype="float32",
         if hw_vmem:
             budget = min(budget, int(hw_vmem))
     it = jnp.dtype(dtype).itemsize
-    Mp = ((M + l.m - 1) // l.m) * l.m
-    Kp = ((K + l.k - 1) // l.k) * l.k
-    Np = ((N + l.n - 1) // l.n) * l.n
-    X, Ks, Z = Mp // l.m, Kp // l.k, Np // l.n
+    X, Ks, Z = part_dims(l, M, K, N, dtype)
+    Mp, Kp, Np = X * l.m, Ks * l.k, Z * l.n
     ca = plan_combine_blocks(X, Ks, l.R, l.m * l.k, dtype, budget)
     cb = plan_combine_blocks(Ks, Z, l.R, l.k * l.n, dtype, budget)
     fg = plan_fused_gemm_blocks(X, Z, Ks, l.R, l.m, l.n, dtype, budget)
@@ -110,9 +159,9 @@ def plan_fused_gemm_blocks(X: int, Z: int, Y: int, R: int, m: int, n: int, dtype
     """Pick (bx, bz, by) fitting the budget, preferring large MXU-aligned tiles."""
     it = jnp.dtype(dtype).itemsize
     best, best_score = None, -1.0
-    for bx in _align_candidates(X):
-        for bz in _align_candidates(Z):
-            for by in _align_candidates(Y):
+    for bx in _align_candidates(X, sublane(dtype)):
+        for bz in _align_candidates(Z, LANE):
+            for by in _align_candidates(Y, LANE):
                 if fused_gemm_vmem(bx, bz, by, R, m, n, it) > budget:
                     continue
                 # score: MXU utilization proxy — prefer 128-multiples and

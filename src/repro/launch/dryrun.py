@@ -1,10 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any jax import (jax locks the device
+The line above MUST run before any jax import (jax locks the device
 count at first init). For each cell this driver:
 
   1. builds the production mesh (16x16 single-pod / 2x16x16 multi-pod),
@@ -27,9 +26,9 @@ import traceback
 import jax
 
 import repro.api as falcon
-from repro import compat
 from repro.configs import SHAPE_CELLS, get_config, list_archs
 from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch import specs as SP
 from repro.models import model as M
@@ -112,7 +111,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, out_dir: str | None,
         cs = SP.input_specs(cfg, cell, mesh, opt_dtype=opt_dtype or "float32")
         step = build_step_fn(cfg, cell, mesh, cs, opt_dtype=opt_dtype or "float32",
                              microbatches=microbatches)
-        with compat.set_mesh(mesh), \
+        with jax.set_mesh(mesh), \
                 falcon.use(M.falcon_config_for(cfg, dict(mesh.shape))):
             lowered = step.lower(*cs.args)
             t_lower = time.time() - t0
@@ -198,6 +197,7 @@ def main() -> int:
     ap.add_argument("--tag", default="", help="suffix for the output record")
     ap.add_argument("--notes", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
     archs = list_archs() if (args.all or not args.arch) else [args.arch]

@@ -29,7 +29,7 @@ import repro.api as falcon
 from repro import compat
 from repro.configs import get_config, smoke_config
 from repro.core import plan_cache
-from repro.launch.mesh import make_local_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import ServeEngine, StepLoop
 from repro.train.steps import make_decode_step, make_prefill_step
@@ -73,9 +73,9 @@ def main() -> None:
                          "serving (--continuous)")
     ap.add_argument("--no-warm", dest="warm", action="store_false")
     ap.add_argument("--mesh", default=None, metavar="DATA,MODEL",
-                    help="shard the engine over a real (data, model) mesh, "
-                         "e.g. --mesh 1,8 for 8-way tensor parallelism "
-                         "(--continuous); simulate devices on one host with "
+                    help="shard over a real (data, model) mesh, e.g. "
+                         "--mesh 1,8 for 8-way tensor parallelism (default: "
+                         "one device); simulate devices on one host with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count=N")
     ap.add_argument("--speculate", type=int, default=0, metavar="GAMMA",
                     help="speculative decoding: a self-draft proposes GAMMA "
@@ -98,6 +98,7 @@ def main() -> None:
                          "emitted (--continuous)")
     args = ap.parse_args()
     args.mesh_shape = _parse_mesh(args.mesh)
+    enable_compile_cache()
 
     if args.plan_cache:
         cache = plan_cache.configure(path=args.plan_cache)
@@ -183,7 +184,10 @@ def _run_continuous(cfg, args) -> None:
 
 
 def _run_oneshot(cfg, args) -> None:
-    mesh = make_local_mesh()
+    # one device unless --mesh asks for more
+    mesh = compat.make_mesh((args.mesh_shape.get("data", 1),
+                             args.mesh_shape.get("model", 1)),
+                            ("data", "model"))
     fcfg = M.falcon_config_for(cfg, dict(mesh.shape))
     if args.quant:
         fcfg = dataclasses.replace(fcfg, quantize=True)
@@ -199,7 +203,7 @@ def _run_oneshot(cfg, args) -> None:
     prefill = jax.jit(make_prefill_step(cfg, max_len=max_len))
     decode = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
 
-    with compat.set_mesh(mesh), falcon.use(fcfg):
+    with jax.set_mesh(mesh), falcon.use(fcfg):
         if args.precombine:
             # Offline Combine B against the prefill shape (the M the Decision
             # Module should price); decode re-decides per its own tiny M.

@@ -14,9 +14,9 @@ import logging
 import jax
 
 import repro.api as falcon
-from repro import compat
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init
@@ -42,6 +42,7 @@ def main() -> None:
                     help="int8-compressed data-parallel gradient all-reduce")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(levelname)s %(message)s")
 
@@ -53,7 +54,7 @@ def main() -> None:
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     opt_cfg = AdamWConfig(lr=args.lr)
     opt_state = adamw_init(params, opt_cfg)
-    with compat.set_mesh(mesh), falcon.use(fcfg):
+    with jax.set_mesh(mesh), falcon.use(fcfg):
         psh = SH.param_sharding(params, mesh, rules)
         params = jax.device_put(params, psh)
         opt_state = jax.device_put(opt_state, {

@@ -11,6 +11,7 @@ All projections are FalconGEMM-dispatched.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +83,14 @@ def padded_vocab(cfg: ModelConfig) -> int:
     return -(-cfg.vocab_size // 256) * 256
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> dict:
+    """Random parameters for ``cfg`` from ``key``.
+
+    One compiled program: the per-layer stack is built in place, and a caller
+    that wraps it in ``jax.jit(..., out_shardings=...)`` gets each leaf
+    created directly in its sharded layout, with the same values.
+    """
     ke, kl, kh = jax.random.split(key, 3)
     dt = jnp.dtype(cfg.dtype)
     Vp = padded_vocab(cfg)
@@ -95,8 +103,7 @@ def init_params(cfg: ModelConfig, key) -> dict:
         params["embed"] = (jax.random.normal(
             ke, (Vp, cfg.d_model), jnp.float32) * 0.02).astype(dt)
     layer_keys = jax.random.split(kl, cfg.num_layers)
-    per_layer = [_layer_init(k, cfg) for k in layer_keys]
-    params["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per_layer)
+    params["layers"] = jax.vmap(lambda k: _layer_init(k, cfg))(layer_keys)
     params["final_norm"] = L.rmsnorm_init(cfg.d_model, dt)
     if cfg.frontend == "audio_codebooks":
         params["lm_head"] = (jax.random.normal(

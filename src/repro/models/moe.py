@@ -188,7 +188,7 @@ def _moe_shardmap(p, x, top_k, C_global, mesh):
             aux = jax.lax.pmean(aux, dp_axes)
         return y.reshape(Bl, Sl, d), aux
 
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         in_specs=(xspec, P(None, "model"), wg_spec, wu_spec, wd_spec),
         out_specs=(xspec, P()),

@@ -14,7 +14,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 __all__ = ["compressed_psum_mean", "psum_mean"]
 
@@ -22,7 +21,7 @@ __all__ = ["compressed_psum_mean", "psum_mean"]
 def psum_mean(tree, axis_names):
     n = 1
     for a in axis_names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return jax.tree.map(lambda g: jax.lax.psum(g, axis_names) / n, tree)
 
 
@@ -35,7 +34,7 @@ def _q_one(g, axis_names, bits: int):
     total = jax.lax.psum(q.astype(jnp.int32), axis_names)
     n = 1
     for a in axis_names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return (total.astype(jnp.float32) * (scale / levels) / n).astype(g.dtype)
 
 

@@ -48,6 +48,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.api as falcon
 from repro import compat
@@ -150,15 +151,23 @@ class ServeEngine:
             # and warm() pre-plans the quantized buckets.
             self.fcfg = dataclasses.replace(self.fcfg, quantize=True)
         with falcon.use(self.fcfg), self._mesh_ctx():
-            self.params = params if params is not None \
-                else M.init_params(model_cfg, jax.random.PRNGKey(seed))
+            # Tensor-parallel at rest: raw weights are sharded by the rule
+            # table BEFORE precombine, so offline Combine B runs on (and its
+            # B̃ output inherits) the sharded layout. Random weights are made
+            # in that layout, never whole on one device.
+            shard = None
             if self.mesh is not None:
-                # Tensor-parallel at rest: shard raw weights by the rule table
-                # BEFORE precombine, so offline Combine B runs on (and its B̃
-                # output inherits) the sharded layout.
                 rules = SH.make_rules(self.mesh)
-                self.params = jax.device_put(
-                    self.params, SH.param_sharding(self.params, self.mesh, rules))
+                shard = lambda tree: SH.param_sharding(tree, self.mesh, rules)
+            if params is None:
+                key = jax.random.PRNGKey(seed)
+                out = None if shard is None else \
+                    shard(jax.eval_shape(M.init_params, model_cfg, key))
+                params = jax.jit(M.init_params, static_argnums=0,
+                                 out_shardings=out)(model_cfg, key)
+            elif shard is not None:
+                params = jax.device_put(params, shard(params))
+            self.params = params
             self.draft: DraftModel | None = draft
             if self.gamma and self.draft is None:
                 # built from RAW params: a layer slice of a precombined tree
@@ -192,13 +201,20 @@ class ServeEngine:
             # Replicated-then-gathered decode: the KV cache lives replicated on
             # every device; each step's projections run tensor-parallel and the
             # (small) per-step activations gather back before the cache write.
-            from jax.sharding import NamedSharding, PartitionSpec as P
             self.cache = jax.device_put(
                 self.cache, NamedSharding(self.mesh, P()))
         self.pos = np.zeros(max_slots, np.int32)   # per-slot next write index
-        self._prefill_fn = jax.jit(make_chunk_prefill_step(model_cfg))
-        self._decode_fn = jax.jit(make_decode_step(model_cfg))
-        self._verify_fn = jax.jit(make_verify_step(model_cfg))
+        # Under a mesh, step outputs (logits, cache rows) come back replicated
+        # like the cache itself. Left to GSPMD, a KV-head dim that the model
+        # axis does not divide comes back partially tiled, in a layout the
+        # eager slot update cannot index.
+        out = None if self.mesh is None else NamedSharding(self.mesh, P())
+        self._prefill_fn = jax.jit(make_chunk_prefill_step(model_cfg),
+                                   out_shardings=out)
+        self._decode_fn = jax.jit(make_decode_step(model_cfg),
+                                  out_shardings=out)
+        self._verify_fn = jax.jit(make_verify_step(model_cfg),
+                                  out_shardings=out)
         self._compiled: set[tuple] = set()          # step shapes already traced
         self._submit_lock = threading.Lock()
 
@@ -223,7 +239,7 @@ class ServeEngine:
         return compat.make_mesh((d, m), ("data", "model"))
 
     def _mesh_ctx(self):
-        return compat.set_mesh(self.mesh) if self.mesh is not None \
+        return jax.set_mesh(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
     # -- admission ----------------------------------------------------------

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core import engine
 from repro.models import model as M
@@ -272,7 +271,7 @@ def make_compressed_dp_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig, mesh,
         loss = jax.lax.pmean(loss, dp_axes)
         return loss, metrics, grads
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         sharded_grads, mesh=mesh,
         in_specs=(P(), {"tokens": batch_spec, "labels": batch_spec}),
         out_specs=(P(), P(), P()),

@@ -424,7 +424,7 @@ def test_compat_mesh_roundtrip():
     from repro import compat
     assert compat.get_abstract_mesh() is None
     mesh = compat.make_mesh((1,), ("data",))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         m = compat.get_abstract_mesh()
         assert m is not None and "data" in m.axis_names
     assert compat.get_abstract_mesh() is None
@@ -434,7 +434,7 @@ def test_compat_shard_map_single_device():
     from jax.sharding import PartitionSpec as P
     from repro import compat
     mesh = compat.make_mesh((1,), ("data",))
-    f = compat.shard_map(lambda x: x * 2, mesh=mesh, in_specs=P("data"),
-                         out_specs=P("data"), check_vma=False)
+    f = jax.shard_map(lambda x: x * 2, mesh=mesh, in_specs=P("data"),
+                      out_specs=P("data"), check_vma=False)
     np.testing.assert_array_equal(
         np.asarray(f(jnp.arange(4.0))), np.arange(4.0) * 2)

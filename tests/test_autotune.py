@@ -1,5 +1,6 @@
 """Autotune: deterministic calibration, profile persistence, block-plan export."""
 import json
+import types
 
 import numpy as np
 import pytest
@@ -124,3 +125,25 @@ def test_tune_cli_end_to_end(tmp_path, capsys):
     warmed = plan_cache.PlanCache(
         path=str(tmp_path / "cli_autotuned.plans.json"))
     assert len(warmed) > 0
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", "tpu_v5e"),
+    ("cpu", "cpu", "tpu_v5e"),
+])
+def test_device_profile_follows_device_kind(platform, kind, want):
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    assert hw.device_profile_name(dev) == want
+
+
+def test_unlisted_tpu_kind_raises():
+    dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v9 hypothetical")
+    with pytest.raises(KeyError, match="no hardware profile"):
+        hw.device_profile_name(dev)
+
+
+def test_default_config_prices_the_attached_device_and_interprets_on_cpu():
+    # the suite runs on the CPU: kernels interpret, and the default config
+    # prices the analytic v5e profile the plans are made for
+    assert hw.interpret_kernels()
+    assert FalconConfig().profile is hw.get_profile("tpu_v5e")

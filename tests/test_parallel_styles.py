@@ -21,7 +21,7 @@ def test_fsdp_only_matches_tp_numerics():
         for style in ("tp", "fsdp_only"):
             c2 = dataclasses.replace(cfg, parallel_style=style)
             tok = SH.set_parallel_style(style)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 rules = SH.make_rules(mesh, fsdp=True, style=style)
                 psh = SH.param_sharding(params, mesh, rules)
                 p2 = jax.device_put(params, psh)

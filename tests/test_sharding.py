@@ -41,7 +41,7 @@ def test_moe_shardmap_equals_dense():
         with falcon.use(falcon.FalconConfig(enabled=False)):
             y0, _ = MOE._moe_dense(p, x, 2, 256)
             mesh = compat.make_mesh((4, 2), ("data", "model"))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 y1, _ = jax.jit(lambda p_, x_: MOE.moe_apply(
                     p_, x_, 2, 1.25, deterministic_capacity=256))(p, x)
         err = float(jnp.max(jnp.abs(y0 - y1)))
@@ -75,7 +75,7 @@ def test_moe_shardmap_precombined_without_raw_weight():
                 assert p[k].w is None and p[k].bt is not None, k
             y0, _ = MOE._moe_dense(p, x, 2, 256)
             mesh = compat.make_mesh((4, 2), ("data", "model"))
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 y1, _ = jax.jit(lambda p_, x_: MOE.moe_apply(
                     p_, x_, 2, 1.25, deterministic_capacity=256))(p, x)
         err = float(jnp.max(jnp.abs(y0 - y1)))
@@ -98,8 +98,8 @@ def test_compressed_psum_accuracy_and_train_step():
             exact = psum_mean({"g": gl}, ("data",))["g"]
             comp = compressed_psum_mean({"g": gl}, ("data",))["g"]
             return exact, comp
-        with compat.set_mesh(mesh):
-            exact, comp = jax.jit(compat.shard_map(
+        with jax.set_mesh(mesh):
+            exact, comp = jax.jit(jax.shard_map(
                 body, in_specs=P("data", None),
                 out_specs=(P(None, None), P(None, None)), check_vma=False))(g)
         rel = float(jnp.linalg.norm(exact - comp) / jnp.linalg.norm(exact))
@@ -122,7 +122,7 @@ def test_compressed_psum_accuracy_and_train_step():
         # short smoke run, reducing the "learns" assertion to batch noise.
         step = jax.jit(make_compressed_dp_train_step(cfg, oc, mesh, warmup=1))
         batch = data.batch(0)  # fixed batch: loss must drop deterministically
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             losses = []
             for s in range(8):
                 params, ost, m = step(params, ost, batch, s)

@@ -13,7 +13,7 @@ def test_shard_map_local_backend():
         x = jax.random.normal(jax.random.PRNGKey(0), (16, 12, 48))
         w = jax.random.normal(jax.random.PRNGKey(1), (48, 40))
         cfg = FalconConfig(mode="strassen", backend="shard_map_local")
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda a, b: falcon_dense(a, b, cfg))(x, w)
             # grads flow through the shard_map + LCMA path
             g = jax.jit(jax.grad(lambda b: jnp.sum(falcon_dense(x, b, cfg) ** 2)))(w)
