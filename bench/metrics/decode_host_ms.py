@@ -1,0 +1,8 @@
+"""Mean host time of a decode step: the benchmark span around
+``ServeEngine.step`` minus the device time inside it (serve engine layer:
+eager KV gather and scatter, host argmax, dispatch)."""
+from bench import readers
+
+
+def read(run):
+    return readers.mean_host_ms(run, "decode")
