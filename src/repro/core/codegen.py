@@ -193,6 +193,10 @@ def _generate_cached(l_id: int, key: tuple) -> GeneratedLCMA:  # pragma: no cove
 
 _CACHE: dict[tuple, GeneratedLCMA] = {}
 
+_STAGE_SCOPES = {"combine_a": "falcon.combine_a",
+                 "gemm_stage": "falcon.lcma_gemm",
+                 "combine_h": "falcon.combine_h"}
+
 
 def generate(l: LCMA, options: CodegenOptions | None = None) -> GeneratedLCMA:
     """Generate + compile the LCMA implementation for scheme ``l``."""
@@ -204,6 +208,10 @@ def generate(l: LCMA, options: CodegenOptions | None = None) -> GeneratedLCMA:
     src = _emit_source(l, o)
     ns: dict = {"jax": jax, "jnp": jnp}
     exec(compile(src, f"<lcma:{l.name}>", "exec"), ns)  # noqa: S102 - trusted, self-emitted
+    # name the stages in the lowered program's op metadata, so a device
+    # trace attributes each op to its stage; lcma_matmul looks them up here
+    for stage, scope in _STAGE_SCOPES.items():
+        ns[stage] = jax.named_scope(scope)(ns[stage])
     gen = GeneratedLCMA(
         lcma=l,
         options=o,

@@ -324,12 +324,18 @@ def precombine_params(params, cfg: FalconConfig | None = None,
     return out, n_planned
 
 
+@jax.named_scope("falcon.gemm")
+def _gemm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """The plain-GEMM branch of a 2-D dispatch, named in the op metadata."""
+    return jnp.matmul(a, b)
+
+
 def _apply_planned(x: jnp.ndarray, pw: PlannedWeight,
                    cfg: FalconConfig) -> jnp.ndarray:
     """x (..., K) @ PlannedWeight -> (..., N); serving fast path."""
     *lead, K = x.shape
     if pw.algo is None:
-        return jnp.matmul(x, pw.w)
+        return _gemm(x, pw.w)
     be = backends.get_backend(cfg.backend)
     if be.dense_hook is not None and pw.w is not None:
         # Layer-level placements (e.g. shard_map_local's per-device local
@@ -357,7 +363,7 @@ def _apply_planned(x: jnp.ndarray, pw: PlannedWeight,
         use_pre = d.use_lcma
         use_quant = pw.quantized and d.quantized
     if not use_pre:
-        return jnp.matmul(x, pw.w)
+        return _gemm(x, pw.w)
     if use_quant and be.apply_quant is not None:
         if cfg.planned_vjp and pw.w is not None:
             out2 = _pw_quant_core(cfg, pw.algo, pw.n)(
@@ -578,7 +584,7 @@ def _dispatch2d(a2: jnp.ndarray, b2: jnp.ndarray,
     d = plan(M, K, N, cfg, str(a2.dtype))
     if d.use_lcma:
         return _lcma_apply(a2, b2, d.algo, cfg)
-    return jnp.matmul(a2, b2)
+    return _gemm(a2, b2)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1077,7 +1083,7 @@ def matmul(a: jnp.ndarray, b, cfg: FalconConfig | None = None,
         c = _planned_core(cfg)(a.reshape(Mflat, K) if lead else a, b)
         return c.reshape(*lead, M, N) if lead else c
     if not d.use_lcma:
-        return jnp.matmul(a, b)
+        return _gemm(a, b)
     a2 = a.reshape(Mflat, K) if lead else a
     c = _lcma_apply(a2, b, d.algo, cfg)
     return c.reshape(*lead, M, N) if lead else c

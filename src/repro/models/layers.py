@@ -170,7 +170,7 @@ def attn_apply(p: dict, x: jnp.ndarray, dims: AttnDims, positions, theta: float,
     Dispatch policy comes from the context config; ``fcfg`` is a deprecated
     per-call override.
     """
-    with engine.deprecated_fcfg(fcfg, "attn_apply"):
+    with engine.deprecated_fcfg(fcfg, "attn_apply"), jax.named_scope("attn"):
         B, S, d = x.shape
         H, Hkv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
         q = shard_act(falcon_dense(x, p["w_q"]).reshape(B, S, H, hd),
@@ -230,7 +230,7 @@ def mlp_init(key, d: int, d_ff: int, dtype, mlp_type: str = "swiglu") -> dict:
 
 def mlp_apply(p: dict, x: jnp.ndarray,
               fcfg: FalconConfig | None = None) -> jnp.ndarray:
-    with engine.deprecated_fcfg(fcfg, "mlp_apply"):
+    with engine.deprecated_fcfg(fcfg, "mlp_apply"), jax.named_scope("mlp"):
         u = shard_act(falcon_dense(x, p["mlp_up"]), BATCH, None, "model")
         if "mlp_gate" in p:
             g = shard_act(falcon_dense(x, p["mlp_gate"]), BATCH, None, "model")

@@ -290,7 +290,8 @@ def _forward(params, cfg: ModelConfig, tokens, *, patch_embeds=None,
 
 def compute_logits(params, cfg: ModelConfig, x, fcfg: FalconConfig | None = None):
     with engine.config_scope(fcfg, "compute_logits",
-                             lambda: falcon_config_for(cfg)):
+                             lambda: falcon_config_for(cfg)), \
+            jax.named_scope("lm_head"):
         return _compute_logits(params, cfg, x)
 
 

@@ -48,6 +48,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.api as falcon
@@ -361,16 +362,30 @@ class ServeEngine:
     # -- step loop -----------------------------------------------------------
 
     def step(self) -> bool:
-        """Run one scheduler-selected micro-batch. False when idle."""
-        work = self.scheduler.next_work()
+        """Run one scheduler-selected micro-batch. False when idle.
+
+        Each phase is timed into ``stats.phase_s`` and, while the profiler
+        records, written as a ``serve.<phase>`` span: ``serve.schedule``,
+        then one ``serve.prefill``/``serve.decode``/``serve.verify`` span
+        per step, whose metadata names its bucket and request ids.
+        """
+        with self.stats.phase("schedule"):
+            work = self.scheduler.next_work()
         if work is None:
             return False
         if isinstance(work, PrefillWork):
-            self._run_prefill(work)
-        elif self.gamma:
-            self._run_spec_decode(work)
+            kind, run = "prefill", self._run_prefill
+            bucket = f"{work.batch_pad}x{work.seq_pad}"
         else:
-            self._run_decode(work)
+            kind, run = ("verify", self._run_spec_decode) if self.gamma \
+                else ("decode", self._run_decode)
+            bucket = str(work.batch_pad)
+        meta = {}
+        if TraceAnnotation.is_enabled():    # no formatting when untraced
+            meta = {"bucket": bucket,
+                    "rids": " ".join(str(r.rid) for r in work.requests)}
+        with self.stats.phase(kind, **meta):
+            run(work)
         return True
 
     def run(self, max_steps: int | None = None) -> list[Request]:
@@ -394,52 +409,58 @@ class ServeEngine:
     def _run_prefill(self, work: PrefillWork) -> None:
         B, S = work.batch_pad, work.seq_pad
         self._note_shape(("prefill", B, S))
+        phase = self.stats.phase
         k = len(work.requests)
         # first chunk of a prefix hit: copy the reused KV/state into the slot
         # before this chunk's rows are gathered
         for i, r in enumerate(work.requests):
             if r.prefix_entry is not None and work.starts[i] == r.prefix_len:
                 self._load_prefix(work.slots[i], r)
-        toks = np.zeros((B, S), np.int32)
-        last = np.zeros((B,), np.int32)
-        start = np.zeros((B,), np.int32)
-        for i, r in enumerate(work.requests):
-            n = work.lengths[i]
-            toks[i, :n] = r.prompt[work.starts[i]:work.starts[i] + n]
-            last[i] = n - 1
-            start[i] = work.starts[i]
-        t0 = time.perf_counter()
-        with falcon.use(self.fcfg), self._mesh_ctx():
+        with phase("inputs"):
+            toks = np.zeros((B, S), np.int32)
+            last = np.zeros((B,), np.int32)
+            start = np.zeros((B,), np.int32)
+            for i, r in enumerate(work.requests):
+                n = work.lengths[i]
+                toks[i, :n] = r.prompt[work.starts[i]:work.starts[i] + n]
+                last[i] = n - 1
+                start[i] = work.starts[i]
             idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (B - k))
-            rows = jax.tree.map(lambda c: c[:, idx], self.cache)
-            logits, new_rows = self._prefill_fn(
-                self.params, rows, jnp.asarray(toks), jnp.asarray(start),
-                jnp.asarray(last))
-            jax.block_until_ready(logits)
-            slots = jnp.asarray(work.slots)
-            # pad rows i >= k are sliced off; pad positions inside a row are
-            # overwritten by decode before the validity mask admits them
-            self.cache = jax.tree.map(
-                lambda c, nc: c.at[:, slots].set(nc[:, :k].astype(c.dtype)),
-                self.cache, new_rows)
+        with falcon.use(self.fcfg), self._mesh_ctx():
+            with phase("kv_gather"):
+                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
+            with phase("forward"):
+                logits, new_rows = self._prefill_fn(
+                    self.params, rows, jnp.asarray(toks), jnp.asarray(start),
+                    jnp.asarray(last))
+                jax.block_until_ready(logits)
+            with phase("kv_scatter"):
+                slots = jnp.asarray(work.slots)
+                # pad rows i >= k are sliced off; pad positions inside a row
+                # are overwritten by decode before the validity mask admits
+                # them
+                self.cache = jax.tree.map(
+                    lambda c, nc: c.at[:, slots].set(nc[:, :k].astype(c.dtype)),
+                    self.cache, new_rows)
             if self.draft is not None:
-                self.draft.prefill_chunk(toks, start, last, work.slots, k)
-        step_logits = np.asarray(logits[:, -1])
-        now = time.perf_counter()
-        self.stats.t_prefill += now - t0
-        self.stats.prefill_steps += 1
-        self.stats.prompt_tokens += work.real_tokens
-        self.stats.prefill_padded_tokens += work.padded_tokens
-        for i, r in enumerate(work.requests):
-            r.prefilled = work.starts[i] + work.lengths[i]
-            if not work.final[i]:
-                continue                    # chunk done; more prompt to go
-            if self.prefix is not None and r.prompt_len > 1:
-                self._insert_prefix(r, work.slots[i])
-            self.pos[work.slots[i]] = r.prompt_len
-            r.first_token_t = now
-            self.stats.generated_tokens += 1
-            self._emit(r, int(np.argmax(step_logits[i])), step_logits[i])
+                with phase("draft"):
+                    self.draft.prefill_chunk(toks, start, last, work.slots, k)
+        with phase("sample"):
+            step_logits = np.asarray(logits[:, -1])
+            now = time.perf_counter()
+            self.stats.prefill_steps += 1
+            self.stats.prompt_tokens += work.real_tokens
+            self.stats.prefill_padded_tokens += work.padded_tokens
+            for i, r in enumerate(work.requests):
+                r.prefilled = work.starts[i] + work.lengths[i]
+                if not work.final[i]:
+                    continue                # chunk done; more prompt to go
+                if self.prefix is not None and r.prompt_len > 1:
+                    self._insert_prefix(r, work.slots[i])
+                self.pos[work.slots[i]] = r.prompt_len
+                r.first_token_t = now
+                self.stats.generated_tokens += 1
+                self._emit(r, int(np.argmax(step_logits[i])), step_logits[i])
 
     # -- prefix cache --------------------------------------------------------
 
@@ -486,31 +507,36 @@ class ServeEngine:
         k = len(work.slots)
         b = work.batch_pad
         self._note_shape(("decode", b))
-        idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
-        toks = np.zeros((b, 1), np.int32)
-        pos = np.zeros((b,), np.int32)
-        for i, r in enumerate(work.requests):
-            toks[i, 0] = r.generated[-1]
-            pos[i] = self.pos[work.slots[i]]
-        t0 = time.perf_counter()
+        phase = self.stats.phase
+        with phase("inputs"):
+            idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
+            toks = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            for i, r in enumerate(work.requests):
+                toks[i, 0] = r.generated[-1]
+                pos[i] = self.pos[work.slots[i]]
         with falcon.use(self.fcfg), self._mesh_ctx():
-            rows = jax.tree.map(lambda c: c[:, idx], self.cache)
-            logits, new_rows = self._decode_fn(
-                self.params, rows, jnp.asarray(toks), jnp.asarray(pos))
-            jax.block_until_ready(logits)
-        slots = jnp.asarray(work.slots)
-        self.cache = jax.tree.map(
-            lambda c, nc: c.at[:, slots].set(nc[:, :k]), self.cache, new_rows)
-        step_logits = np.asarray(logits[:, -1])
-        self.stats.t_decode += time.perf_counter() - t0
-        self.stats.decode_steps += 1
-        self.stats.generated_tokens += work.real_tokens
-        self.stats.decode_real_rows += work.real_tokens
-        self.stats.decode_emitted_tokens += work.real_tokens
-        self.stats.decode_padded_tokens += work.padded_tokens
-        for i, r in enumerate(work.requests):
-            self.pos[work.slots[i]] += 1
-            self._emit(r, int(np.argmax(step_logits[i])), step_logits[i])
+            with phase("kv_gather"):
+                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
+            with phase("forward"):
+                logits, new_rows = self._decode_fn(
+                    self.params, rows, jnp.asarray(toks), jnp.asarray(pos))
+                jax.block_until_ready(logits)
+        with phase("kv_scatter"):
+            slots = jnp.asarray(work.slots)
+            self.cache = jax.tree.map(
+                lambda c, nc: c.at[:, slots].set(nc[:, :k]), self.cache,
+                new_rows)
+        with phase("sample"):
+            step_logits = np.asarray(logits[:, -1])
+            self.stats.decode_steps += 1
+            self.stats.generated_tokens += work.real_tokens
+            self.stats.decode_real_rows += work.real_tokens
+            self.stats.decode_emitted_tokens += work.real_tokens
+            self.stats.decode_padded_tokens += work.padded_tokens
+            for i, r in enumerate(work.requests):
+                self.pos[work.slots[i]] += 1
+                self._emit(r, int(np.argmax(step_logits[i])), step_logits[i])
 
     def _run_spec_decode(self, work: DecodeWork) -> None:
         """One speculative round: draft γ, verify in one forward, accept.
@@ -526,47 +552,52 @@ class ServeEngine:
         b = work.batch_pad
         g = self.gamma
         self._note_shape(("spec", b))
-        idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
-        last2 = np.zeros((b, 2), np.int32)
-        pos = np.zeros((b,), np.int32)
-        for i, r in enumerate(work.requests):
-            last2[i] = r.tokens[-2:]
-            pos[i] = self.pos[work.slots[i]]
-        last2[k:] = last2[k - 1]            # pad rows mirror the last real row
-        pos[k:] = pos[k - 1]
-        t0 = time.perf_counter()
+        phase = self.stats.phase
+        with phase("inputs"):
+            idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
+            last2 = np.zeros((b, 2), np.int32)
+            pos = np.zeros((b,), np.int32)
+            for i, r in enumerate(work.requests):
+                last2[i] = r.tokens[-2:]
+                pos[i] = self.pos[work.slots[i]]
+            last2[k:] = last2[k - 1]        # pad rows mirror the last real row
+            pos[k:] = pos[k - 1]
         with falcon.use(self.fcfg), self._mesh_ctx():
-            drafts = self.draft.propose(idx, last2, pos, g, k)   # (b, γ)
-            verify = np.concatenate([last2[:, 1:], drafts], axis=1)
-            rows = jax.tree.map(lambda c: c[:, idx], self.cache)
-            logits, new_rows = self._verify_fn(
-                self.params, rows, jnp.asarray(verify), jnp.asarray(pos))
-            jax.block_until_ready(logits)
-            slots = jnp.asarray(work.slots)
-            self.cache = jax.tree.map(
-                lambda c, nc: c.at[:, slots].set(nc[:, :k]),
-                self.cache, new_rows)
-        logits_np = np.asarray(logits)                           # (b, γ+1, V)
-        greedy = np.argmax(logits_np, axis=-1)
-        self.stats.t_decode += time.perf_counter() - t0
-        self.stats.verify_steps += 1
-        self.stats.drafted_tokens += g * k
-        self.stats.decode_real_rows += k * (g + 1)
-        self.stats.decode_padded_tokens += b * (g + 1)
-        for i, r in enumerate(work.requests):
-            n_acc = 0
-            while n_acc < g and int(drafts[i, n_acc]) == int(greedy[i, n_acc]):
-                n_acc += 1
-            self.stats.accepted_tokens += n_acc
-            emitted = 0
-            for j in range(n_acc + 1):
-                emitted += 1
-                self._emit(r, int(greedy[i, j]), logits_np[i, j])
-                if r.done:
-                    break                   # budget/eos cut mid-acceptance
-            self.pos[work.slots[i]] += emitted
-            self.stats.generated_tokens += emitted
-            self.stats.decode_emitted_tokens += emitted
+            with phase("draft"):
+                drafts = self.draft.propose(idx, last2, pos, g, k)   # (b, γ)
+                verify = np.concatenate([last2[:, 1:], drafts], axis=1)
+            with phase("kv_gather"):
+                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
+            with phase("forward"):
+                logits, new_rows = self._verify_fn(
+                    self.params, rows, jnp.asarray(verify), jnp.asarray(pos))
+                jax.block_until_ready(logits)
+            with phase("kv_scatter"):
+                slots = jnp.asarray(work.slots)
+                self.cache = jax.tree.map(
+                    lambda c, nc: c.at[:, slots].set(nc[:, :k]),
+                    self.cache, new_rows)
+        with phase("sample"):
+            logits_np = np.asarray(logits)                       # (b, γ+1, V)
+            greedy = np.argmax(logits_np, axis=-1)
+            self.stats.verify_steps += 1
+            self.stats.drafted_tokens += g * k
+            self.stats.decode_real_rows += k * (g + 1)
+            self.stats.decode_padded_tokens += b * (g + 1)
+            for i, r in enumerate(work.requests):
+                n_acc = 0
+                while n_acc < g and int(drafts[i, n_acc]) == int(greedy[i, n_acc]):
+                    n_acc += 1
+                self.stats.accepted_tokens += n_acc
+                emitted = 0
+                for j in range(n_acc + 1):
+                    emitted += 1
+                    self._emit(r, int(greedy[i, j]), logits_np[i, j])
+                    if r.done:
+                        break               # budget/eos cut mid-acceptance
+                self.pos[work.slots[i]] += emitted
+                self.stats.generated_tokens += emitted
+                self.stats.decode_emitted_tokens += emitted
 
     def _emit(self, req: Request, tok: int, logits_row=None) -> None:
         """Deliver one generated token; retire the request when finished."""

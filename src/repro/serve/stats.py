@@ -3,12 +3,30 @@
 Counters are plain ints/floats updated by the step loop (single consumer
 thread); derived rates are properties so a dashboard or test reads one
 coherent snapshot via :meth:`ServeStats.as_dict`.
+
+The step loop times its phases with :meth:`ServeStats.phase`, which also
+writes each phase as a ``serve.<phase>`` span into the profiler's trace
+when a trace is being recorded (same clock as the device's operations).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 
-__all__ = ["ServeStats"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["PHASES", "ServeStats"]
+
+# Phases of the step loop. ``schedule`` runs before every step; each step is
+# one ``prefill``, ``decode`` or ``verify`` phase, which holds the others
+# (``draft`` only under speculative decoding).
+PHASES = ("schedule", "prefill", "decode", "verify", "inputs", "draft",
+          "kv_gather", "forward", "kv_scatter", "sample")
+
+
+def _per_phase() -> dict:
+    return dict.fromkeys(PHASES, 0.0)
 
 
 @dataclasses.dataclass
@@ -49,12 +67,35 @@ class ServeStats:
     warmed_shapes: int = 0
     warm_plans: int = 0
     t_warm: float = 0.0
-    # phase wall-clock (step dispatch + device time, excludes warmup)
-    t_prefill: float = 0.0
-    t_decode: float = 0.0
+    # wall clock per phase (seconds, excludes warmup): the total and the
+    # longest single occurrence
+    phase_s: dict = dataclasses.field(default_factory=_per_phase)
+    phase_max_s: dict = dataclasses.field(default_factory=_per_phase)
     # request lifecycle
     requests_admitted: int = 0
     requests_finished: int = 0
+
+    @contextlib.contextmanager
+    def phase(self, name: str, **meta):
+        """Time one phase; a ``serve.<name>`` span in the trace, with
+        ``meta`` as its metadata."""
+        t0 = time.perf_counter()
+        with TraceAnnotation(f"serve.{name}", **meta):
+            yield
+        dt = time.perf_counter() - t0
+        self.phase_s[name] += dt
+        if dt > self.phase_max_s[name]:
+            self.phase_max_s[name] = dt
+
+    @property
+    def t_prefill(self) -> float:
+        """Wall clock of prefill steps (dispatch + device time)."""
+        return self.phase_s["prefill"]
+
+    @property
+    def t_decode(self) -> float:
+        """Wall clock of decode and verify steps."""
+        return self.phase_s["decode"] + self.phase_s["verify"]
 
     @property
     def steps(self) -> int:
@@ -111,8 +152,10 @@ class ServeStats:
         return self.real_tokens / t if t else 0.0
 
     def as_dict(self) -> dict:
-        d = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)}
+        d = dataclasses.asdict(self)
         d.update(
+            t_prefill=self.t_prefill,
+            t_decode=self.t_decode,
             steps=self.steps,
             bucket_hit_rate=round(self.bucket_hit_rate, 4),
             padding_waste=round(self.padding_waste, 4),

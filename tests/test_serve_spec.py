@@ -264,12 +264,26 @@ def test_serve_stats_as_dict_keys_are_stable():
         "decode_padded_tokens", "drafted_tokens", "accepted_tokens",
         "prefix_hits", "prefix_misses", "prefix_tokens_reused",
         "bucket_hits", "bucket_misses", "warmed_shapes", "warm_plans",
-        "t_warm", "t_prefill", "t_decode", "requests_admitted",
+        "t_warm", "t_prefill", "t_decode", "phase_s", "phase_max_s",
+        "requests_admitted",
         "requests_finished", "bucket_hit_rate", "padding_waste",
         "tokens_per_s", "decode_tokens_per_s", "acceptance_rate",
         "prefix_hit_rate",
     }
     assert set(ServeStats().as_dict()) == expected
+
+
+def test_spec_phases_nest_in_prefill_and_verify_steps(spec_served):
+    """Under speculation every decode step is a verify round; the draft's
+    work is its own phase, and the phases nested in the steps take no more
+    time than the steps themselves."""
+    engine, _, _ = spec_served
+    ph = engine.stats.phase_s
+    assert ph["verify"] > 0 and ph["draft"] > 0 and ph["decode"] == 0.0
+    nested = sum(ph[p] for p in ("inputs", "draft", "kv_gather", "forward",
+                                 "kv_scatter", "sample"))
+    assert nested <= ph["prefill"] + ph["verify"]
+    assert engine.stats.t_decode == ph["verify"]
 
 
 def test_serve_stats_rates_safe_on_zero():

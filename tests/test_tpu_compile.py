@@ -9,13 +9,17 @@ tests cannot see either.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.api as falcon
 from repro.core import algorithms as alg
+from repro.core.falcon_gemm import FalconConfig
 from repro.kernels import ops
 from repro.kernels.fused_gemm import (batched_fused_gemm_combine_h,
                                       fused_gemm_combine_h, tiled_matmul)
@@ -129,3 +133,25 @@ def test_offline_weight_quantization_compiles_at_lm_head(one_chip):
     bq, scales = compiled.out_info
     assert bq.shape == (l.R, D_MODEL // l.k, N // l.n)
     assert scales.shape == (l.R, D_MODEL // l.k // 128, N // l.n)
+
+
+def test_lcma_stage_scopes_survive_the_chip_compiler(one_chip):
+    """The jnp-backend Strassen projection against offline B̃ at granite's
+    MLP width keeps the stage names a device trace attributes ops by."""
+    cfg = FalconConfig(mode="strassen", backend="jnp")
+    w = jax.ShapeDtypeStruct((D_MODEL, D_FF), BF16)
+    pw = jax.eval_shape(
+        lambda w_: falcon.plan_weight(w_, cfg=cfg, m_hint=M_PREFILL), w)
+    assert pw.algo == "strassen" and pw.bt is not None
+    pw = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), pw)
+    x = jax.ShapeDtypeStruct((M_PREFILL, D_MODEL), BF16, sharding=one_chip)
+
+    def f(x_, p_):
+        with falcon.use(cfg):
+            return falcon.dense(x_, p_)
+
+    txt = jax.jit(f).lower(x, pw).compile().as_text()
+    scopes = {part for n in re.findall(r'op_name="([^"]*)"', txt)
+              for part in n.split("/")}
+    assert {"falcon.combine_a", "falcon.lcma_gemm", "falcon.combine_h"} <= scopes
