@@ -33,9 +33,15 @@ Correctness of padding: pad rows/positions never leak. Right-padded prefill
 writes pad K/V above each request's true length, but decode validity masks
 ``kpos < pos + S`` and each write covers its positions before they first
 become visible — the same argument covers rejected speculative drafts and
-chunk boundaries; pad *rows* of a micro-batch are sliced off before the slot
-cache update. The engine output is token-exact vs. per-request eager decode
-(``tests/test_serve_engine.py``, ``tests/test_serve_spec.py``).
+chunk boundaries; pad *rows* of a micro-batch carry an out-of-range slot
+index, so the step's write-back drops them. The engine output is token-exact
+vs. per-request eager decode (``tests/test_serve_engine.py``,
+``tests/test_serve_spec.py``).
+
+One program per step: each jitted step takes the whole slot cache (donated)
+and the micro-batch's slot indices, reads its rows, runs the model step and
+writes the rows back in place (:func:`on_slots`). The host dispatches no
+other program against the cache, save the rare prefix-cache load.
 """
 from __future__ import annotations
 
@@ -48,6 +54,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.experimental.layout import with_layout_constraint
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -67,7 +75,51 @@ from .scheduler import DecodeWork, PrefillWork, Scheduler
 from .speculative import DraftModel, SelfDraft
 from .stats import ServeStats
 
-__all__ = ["ServeEngine", "StepLoop"]
+__all__ = ["ServeEngine", "StepLoop", "on_slots"]
+
+
+def on_slots(step, layouts):
+    """``step(params, rows, *args) -> (logits, rows)`` as one program over
+    the whole slot cache: ``(params, cache, slots, *args) -> (logits,
+    cache)``.
+
+    ``slots`` (b,) int32 names each row's slot, real rows first. A pad row
+    carries an out-of-range slot (the cache's slot count): it reads the last
+    real row's slot, as a mirror of that row, and writes nothing, so every
+    slot outside the micro-batch is left bit-identical. Cache leaves (K, V
+    and recurrent ``state``) hold slots on axis 1.
+
+    Rows move as whole-row slices: ``b`` static dynamic slices read them,
+    and a loop over the ``k`` real rows writes them back in place (the
+    engine donates the cache). ``layouts`` (the cache leaves' device
+    layouts) pins the gathered rows to the cache's own layout: left free,
+    the TPU compiler builds them in a padded layout and copies them twice.
+    The function keeps ``step``'s name, so its program keeps the name a
+    device trace reads.
+    """
+    def run(params, cache, slots, *args):
+        n = jax.tree.leaves(cache)[0].shape[1]
+        b = slots.shape[0]
+        k = jnp.sum(slots < n)
+        # slice starts clamp into range: with no real row, pads read the
+        # last slot
+        read = jnp.where(slots < n, slots, slots[jnp.maximum(k - 1, 0)])
+        rows = jax.tree.map(
+            lambda c, lay: with_layout_constraint(jnp.concatenate(
+                [lax.dynamic_slice_in_dim(c, read[i], 1, axis=1)
+                 for i in range(b)], axis=1), lay), cache, layouts)
+        logits, rows = step(params, rows, *args)
+
+        def put(i, cache):
+            return jax.tree.map(
+                lambda c, r: lax.dynamic_update_slice_in_dim(
+                    c, lax.dynamic_slice_in_dim(r, i, 1, axis=1)
+                    .astype(c.dtype), slots[i], axis=1), cache, rows)
+
+        return logits, lax.fori_loop(0, k, put, cache)
+
+    run.__name__ = run.__qualname__ = step.__name__
+    return run
 
 
 class ServeEngine:
@@ -79,7 +131,7 @@ class ServeEngine:
     recurrent state is exact because the serve prefill step zeroes dt on
     right-pad positions (see ``make_chunk_prefill_step``). MoE routing is
     approximate under padding (pad rows contend for expert capacity) but
-    pad rows are sliced off before the slot cache update. Non-token
+    pad rows never write the slot cache. Non-token
     frontends (audio codebooks, vision patches) are rejected — the bucket
     grid assumes one int token stream.
 
@@ -205,17 +257,18 @@ class ServeEngine:
             self.cache = jax.device_put(
                 self.cache, NamedSharding(self.mesh, P()))
         self.pos = np.zeros(max_slots, np.int32)   # per-slot next write index
-        # Under a mesh, step outputs (logits, cache rows) come back replicated
-        # like the cache itself. Left to GSPMD, a KV-head dim that the model
-        # axis does not divide comes back partially tiled, in a layout the
-        # eager slot update cannot index.
+        # Each step is one program that takes the slot cache (donated, so
+        # its write-back is in place) and returns it. Under a mesh, its
+        # outputs (logits, cache) come back replicated like the cache itself:
+        # left to GSPMD, a KV-head dim that the model axis does not divide
+        # comes back partially tiled, and the cache would not alias.
         out = None if self.mesh is None else NamedSharding(self.mesh, P())
-        self._prefill_fn = jax.jit(make_chunk_prefill_step(model_cfg),
-                                   out_shardings=out)
-        self._decode_fn = jax.jit(make_decode_step(model_cfg),
-                                  out_shardings=out)
-        self._verify_fn = jax.jit(make_verify_step(model_cfg),
-                                  out_shardings=out)
+        layouts = jax.tree.map(lambda c: c.format.layout, self.cache)
+        self._prefill_fn, self._decode_fn, self._verify_fn = (
+            jax.jit(on_slots(make(model_cfg), layouts), donate_argnums=1,
+                    out_shardings=out)
+            for make in (make_chunk_prefill_step, make_decode_step,
+                         make_verify_step))
         self._compiled: set[tuple] = set()          # step shapes already traced
         self._submit_lock = threading.Lock()
 
@@ -316,7 +369,9 @@ class ServeEngine:
            including from concurrent engines sharing a warmed cache file.
         2. Each (phase, shape) step function — prefill chunks, decode or
            verify rounds, and the draft's own steps — is traced and compiled
-           once on zero inputs, so no live request ever pays a compile.
+           once on zero inputs, so no live request ever pays a compile. The
+           warm steps run against the real slot cache with every slot index
+           out of range, so they write nothing.
         """
         t0 = time.perf_counter()
         grid = (list(self.policy.prefill_shapes())
@@ -327,24 +382,17 @@ class ServeEngine:
                 dtype=str(self.cfg.dtype), mesh_shape=self.mesh_shape,
                 kv_len=self.max_len, spec_gamma=self.gamma or None)
             for (b, s) in self.policy.prefill_shapes():
-                rows_b = self._broadcast_rows(self.cache, b)
-                jax.block_until_ready(self._prefill_fn(
-                    self.params, rows_b, jnp.zeros((b, s), jnp.int32),
-                    jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32)))
+                z = jnp.zeros((b,), jnp.int32)
+                self._step(self._prefill_fn, self._pad_slots(b),
+                           jnp.zeros((b, s), jnp.int32), z, z)
                 self._compiled.add(("prefill", b, s))
             for b in self.policy.decode_batch:
-                rows_b = self._broadcast_rows(self.cache, b)
-                if self.gamma:
-                    jax.block_until_ready(self._verify_fn(
-                        self.params, rows_b,
-                        jnp.zeros((b, self.gamma + 1), jnp.int32),
-                        jnp.zeros((b,), jnp.int32)))
-                    self._compiled.add(("spec", b))
-                else:
-                    jax.block_until_ready(self._decode_fn(
-                        self.params, rows_b, jnp.zeros((b, 1), jnp.int32),
-                        jnp.zeros((b,), jnp.int32)))
-                    self._compiled.add(("decode", b))
+                fn, key, s = (self._verify_fn, "spec", self.gamma + 1) \
+                    if self.gamma else (self._decode_fn, "decode", 1)
+                self._step(fn, self._pad_slots(b),
+                           jnp.zeros((b, s), jnp.int32),
+                           jnp.zeros((b,), jnp.int32))
+                self._compiled.add((key, b))
             if self.draft is not None:
                 self.draft.warm(self.policy, self.gamma)
         self.stats.warm_plans = n_plans
@@ -353,11 +401,19 @@ class ServeEngine:
         return {"plans": n_plans, "shapes": len(self._compiled),
                 "seconds": self.stats.t_warm}
 
-    @staticmethod
-    def _broadcast_rows(cache, b: int):
-        return jax.tree.map(
-            lambda c: jnp.broadcast_to(
-                c[:, :1], (c.shape[0], b) + c.shape[2:]), cache)
+    def _pad_slots(self, b: int, slots=()) -> jax.Array:
+        """The (b,) slot indices of a micro-batch: its slots, then pad rows
+        at the out-of-range index ``max_slots``, which write nothing."""
+        idx = np.full((b,), self.max_slots, np.int32)
+        idx[:len(slots)] = slots
+        return jnp.asarray(idx)
+
+    def _step(self, fn, slots, *args):
+        """Run one jitted step on the slot cache; keep the cache it returns
+        (the old one was donated) and return the logits, on the host's
+        clock: the step has finished when this returns."""
+        logits, self.cache = fn(self.params, self.cache, slots, *args)
+        return jax.block_until_ready(logits)
 
     # -- step loop -----------------------------------------------------------
 
@@ -412,7 +468,7 @@ class ServeEngine:
         phase = self.stats.phase
         k = len(work.requests)
         # first chunk of a prefix hit: copy the reused KV/state into the slot
-        # before this chunk's rows are gathered
+        # before this chunk's step reads it
         for i, r in enumerate(work.requests):
             if r.prefix_entry is not None and work.starts[i] == r.prefix_len:
                 self._load_prefix(work.slots[i], r)
@@ -425,23 +481,13 @@ class ServeEngine:
                 toks[i, :n] = r.prompt[work.starts[i]:work.starts[i] + n]
                 last[i] = n - 1
                 start[i] = work.starts[i]
-            idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (B - k))
+            slots = self._pad_slots(B, work.slots)
         with falcon.use(self.fcfg), self._mesh_ctx():
-            with phase("kv_gather"):
-                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
             with phase("forward"):
-                logits, new_rows = self._prefill_fn(
-                    self.params, rows, jnp.asarray(toks), jnp.asarray(start),
-                    jnp.asarray(last))
-                jax.block_until_ready(logits)
-            with phase("kv_scatter"):
-                slots = jnp.asarray(work.slots)
-                # pad rows i >= k are sliced off; pad positions inside a row
-                # are overwritten by decode before the validity mask admits
-                # them
-                self.cache = jax.tree.map(
-                    lambda c, nc: c.at[:, slots].set(nc[:, :k].astype(c.dtype)),
-                    self.cache, new_rows)
+                # pad rows write nothing; pad positions inside a row are
+                # overwritten by decode before the validity mask admits them
+                logits = self._step(self._prefill_fn, slots, jnp.asarray(toks),
+                                    jnp.asarray(start), jnp.asarray(last))
             if self.draft is not None:
                 with phase("draft"):
                     self.draft.prefill_chunk(toks, start, last, work.slots, k)
@@ -475,6 +521,7 @@ class ServeEngine:
             new[name] = (c.at[:, slot].set(v) if name == "state"
                          else c.at[:, slot, :n].set(v))
         self.cache = new
+        self.stats.kv_eager_writes += 1
         if self.draft is not None:
             self.draft.load(slot, payload["draft"], n)
         self.prefix.release(entry)
@@ -504,29 +551,19 @@ class ServeEngine:
     # -- decode --------------------------------------------------------------
 
     def _run_decode(self, work: DecodeWork) -> None:
-        k = len(work.slots)
         b = work.batch_pad
         self._note_shape(("decode", b))
         phase = self.stats.phase
         with phase("inputs"):
-            idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
+            slots = self._pad_slots(b, work.slots)
             toks = np.zeros((b, 1), np.int32)
             pos = np.zeros((b,), np.int32)
             for i, r in enumerate(work.requests):
                 toks[i, 0] = r.generated[-1]
                 pos[i] = self.pos[work.slots[i]]
-        with falcon.use(self.fcfg), self._mesh_ctx():
-            with phase("kv_gather"):
-                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
-            with phase("forward"):
-                logits, new_rows = self._decode_fn(
-                    self.params, rows, jnp.asarray(toks), jnp.asarray(pos))
-                jax.block_until_ready(logits)
-        with phase("kv_scatter"):
-            slots = jnp.asarray(work.slots)
-            self.cache = jax.tree.map(
-                lambda c, nc: c.at[:, slots].set(nc[:, :k]), self.cache,
-                new_rows)
+        with falcon.use(self.fcfg), self._mesh_ctx(), phase("forward"):
+            logits = self._step(self._decode_fn, slots, jnp.asarray(toks),
+                                jnp.asarray(pos))
         with phase("sample"):
             step_logits = np.asarray(logits[:, -1])
             self.stats.decode_steps += 1
@@ -554,6 +591,7 @@ class ServeEngine:
         self._note_shape(("spec", b))
         phase = self.stats.phase
         with phase("inputs"):
+            slots = self._pad_slots(b, work.slots)
             idx = jnp.asarray(list(work.slots) + [work.slots[-1]] * (b - k))
             last2 = np.zeros((b, 2), np.int32)
             pos = np.zeros((b,), np.int32)
@@ -566,17 +604,9 @@ class ServeEngine:
             with phase("draft"):
                 drafts = self.draft.propose(idx, last2, pos, g, k)   # (b, γ)
                 verify = np.concatenate([last2[:, 1:], drafts], axis=1)
-            with phase("kv_gather"):
-                rows = jax.tree.map(lambda c: c[:, idx], self.cache)
             with phase("forward"):
-                logits, new_rows = self._verify_fn(
-                    self.params, rows, jnp.asarray(verify), jnp.asarray(pos))
-                jax.block_until_ready(logits)
-            with phase("kv_scatter"):
-                slots = jnp.asarray(work.slots)
-                self.cache = jax.tree.map(
-                    lambda c, nc: c.at[:, slots].set(nc[:, :k]),
-                    self.cache, new_rows)
+                logits = self._step(self._verify_fn, slots,
+                                    jnp.asarray(verify), jnp.asarray(pos))
         with phase("sample"):
             logits_np = np.asarray(logits)                       # (b, γ+1, V)
             greedy = np.argmax(logits_np, axis=-1)

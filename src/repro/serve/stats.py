@@ -20,9 +20,10 @@ __all__ = ["PHASES", "ServeStats"]
 
 # Phases of the step loop. ``schedule`` runs before every step; each step is
 # one ``prefill``, ``decode`` or ``verify`` phase, which holds the others
-# (``draft`` only under speculative decoding).
+# (``draft`` only under speculative decoding). ``forward`` is the one step
+# program, which reads and writes the slot cache itself.
 PHASES = ("schedule", "prefill", "decode", "verify", "inputs", "draft",
-          "kv_gather", "forward", "kv_scatter", "sample")
+          "forward", "sample")
 
 
 def _per_phase() -> dict:
@@ -63,6 +64,9 @@ class ServeStats:
     # or previously seen); a miss pays a fresh trace + compile mid-serve
     bucket_hits: int = 0
     bucket_misses: int = 0
+    # eager programs the engine dispatches against the slot cache outside
+    # its step programs: one per prefix-cache load, none otherwise
+    kv_eager_writes: int = 0
     # warmup provenance
     warmed_shapes: int = 0
     warm_plans: int = 0

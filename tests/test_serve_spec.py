@@ -263,7 +263,8 @@ def test_serve_stats_as_dict_keys_are_stable():
         "decode_emitted_tokens", "prefill_padded_tokens",
         "decode_padded_tokens", "drafted_tokens", "accepted_tokens",
         "prefix_hits", "prefix_misses", "prefix_tokens_reused",
-        "bucket_hits", "bucket_misses", "warmed_shapes", "warm_plans",
+        "bucket_hits", "bucket_misses", "kv_eager_writes", "warmed_shapes",
+        "warm_plans",
         "t_warm", "t_prefill", "t_decode", "phase_s", "phase_max_s",
         "requests_admitted",
         "requests_finished", "bucket_hit_rate", "padding_waste",
@@ -280,8 +281,7 @@ def test_spec_phases_nest_in_prefill_and_verify_steps(spec_served):
     engine, _, _ = spec_served
     ph = engine.stats.phase_s
     assert ph["verify"] > 0 and ph["draft"] > 0 and ph["decode"] == 0.0
-    nested = sum(ph[p] for p in ("inputs", "draft", "kv_gather", "forward",
-                                 "kv_scatter", "sample"))
+    nested = sum(ph[p] for p in ("inputs", "draft", "forward", "sample"))
     assert nested <= ph["prefill"] + ph["verify"]
     assert engine.stats.t_decode == ph["verify"]
 

@@ -22,8 +22,7 @@ from repro.serve.stats import PHASES, ServeStats
 
 CFG = registry.smoke_config("granite_3_2b")
 STEP_KINDS = ("serve.prefill", "serve.decode", "serve.verify")
-CHILDREN = ("serve.inputs", "serve.kv_gather", "serve.forward",
-            "serve.kv_scatter", "serve.sample")
+CHILDREN = ("serve.inputs", "serve.forward", "serve.sample")
 FORCE = FalconConfig(mode="strassen", backend="jnp")
 
 

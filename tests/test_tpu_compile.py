@@ -155,3 +155,40 @@ def test_lcma_stage_scopes_survive_the_chip_compiler(one_chip):
     scopes = {part for n in re.findall(r'op_name="([^"]*)"', txt)
               for part in n.split("/")}
     assert {"falcon.combine_a", "falcon.lcma_gemm", "falcon.combine_h"} <= scopes
+
+
+def test_slot_step_writes_the_donated_cache_in_place(one_chip):
+    """The serve engine's decode step (granite smoke widths, 4 slots, a
+    padded bucket of 2) compiled for the chip: each slot-cache leaf is
+    aliased to its output, so the write-back is in place and the donated
+    cache is not copied."""
+    from repro.configs import registry
+    from repro.models import model as M
+    from repro.serve.engine import on_slots
+    from repro.train.steps import make_decode_step
+
+    cfg = registry.smoke_config("granite_3_2b")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(M.init_params, cfg,
+                                    jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: M.init_cache(cfg, 4, 64)))
+    # the device's default layout of each leaf, as a live cache has it
+    formats = jax.jit(lambda c: c).lower(cache).compile().input_formats[0][0]
+    layouts = jax.tree.map(lambda f: f.layout, formats)
+    b = 2
+    step = jax.jit(on_slots(make_decode_step(cfg), layouts), donate_argnums=1)
+    with falcon.use(FalconConfig(mode="gemm")):
+        txt = step.lower(params, cache,
+                         *on_chip((jax.ShapeDtypeStruct((b,), jnp.int32),
+                                   jax.ShapeDtypeStruct((b, 1), jnp.int32),
+                                   jax.ShapeDtypeStruct((b,), jnp.int32)))
+                         ).compile().as_text()
+    header = txt.splitlines()[0]                    # HloModule ..., attributes
+    pairs = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}, [\w-]*alias\)",
+                            header))
+    n = len(jax.tree.leaves(params))
+    assert pairs == {"1": str(n), "2": str(n + 1)}, pairs   # k, v
