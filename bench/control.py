@@ -9,9 +9,11 @@ cell's own load for a short window (every request drained), and compares a
 sample of the served tokens with the float32 reference: the widest gap by
 which a served token's reference logit lies below the reference's best (the
 lower reading, over sound runs of the program). For the control seeds it
-also runs the reference in float8 (e4m3) and in int8 -- every projection
-and the LM head -- over the same prompts and tokens, and reads the gap of
-the token each puts first (the upper reading is the float8 one's least).
+also runs the reference in each lower precision that the configuration's
+family offers as a control (its ``CONTROLS``, such as float8 e4m3 and int8
+on every projection and the LM head) over the same prompts and tokens, and
+reads the gap of the token each puts first (the upper reading is the least
+of the first control's).
 One process, so that the programs compile once. The benchmark's own runs
 do not run this.
 """
@@ -26,14 +28,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
-CONTROLS = ("fp8", "int8")
 
 
 def readings(cell: dict, seeds, control_seeds, seconds: float,
              log=print) -> list[dict]:
     """One reading per seed: ``widest_gap``, and ``control_gap.<precision>``
     for the control seeds."""
-    from bench import harness, weights
+    from bench import harness
     out = []
     for seed in seeds:
         eng = harness.Engine(cell, seed)
@@ -41,11 +42,11 @@ def readings(cell: dict, seeds, control_seeds, seconds: float,
         sample = harness.served_sample(w["records"], seed,
                                        cell["correct"]["min_tokens"])
         eng.free()
-        params = weights.make(eng.dims, seed)
+        params = eng.reference_weights()
         r = harness.widest_gap(
-            eng.dims, params, sample, harness.reference_seq_len(cell),
-            cell["mix"]["output"]["max"],
-            controls=CONTROLS if seed in control_seeds else ())
+            eng.family, eng.dims, params, sample,
+            harness.reference_seq_len(cell), cell["mix"]["output"]["max"],
+            controls=eng.family.CONTROLS if seed in control_seeds else ())
         r["seed"] = seed
         log(f"reading: {json.dumps(r)}")
         out.append(r)
@@ -63,7 +64,7 @@ def main(argv=None) -> int:
     import jax
     if jax.devices()[0].platform != "tpu":
         sys.exit("control: JAX found no TPU; the readings are taken on the chip")
-    from bench import harness
+    from bench import families, harness
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     cell = harness.load_cell(args.workload)
@@ -72,7 +73,8 @@ def main(argv=None) -> int:
     rs = readings(cell, seeds, ctrl, args.seconds)
     lower = max(r["widest_gap"] for r in rs)
     upper = {c: min((r[f"control_gap.{c}"] for r in rs
-                     if f"control_gap.{c}" in r), default=None) for c in CONTROLS}
+                     if f"control_gap.{c}" in r), default=None)
+             for c in families.load(cell["conf"]).CONTROLS}
     print(json.dumps({"workload": args.workload, "lower": lower,
                       "upper": upper, "readings": rs}))
     return 0

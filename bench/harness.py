@@ -3,7 +3,10 @@
 Data lives in files found by name: ``BENCHMARK.json`` names the cell, its
 configuration and its traffic mix; ``bench/cells/<cell>.json``,
 ``bench/configs/<config>.json`` and ``bench/traffic/<mix>.json`` hold their
-numbers. Nothing here names a cell.
+numbers. Nothing here names a cell or a model family: the configuration
+file names its family, whose module (``bench/families/<family>.py``) holds
+the model's arithmetic, and the cell file may name a mesh
+(``bench/layout.py``).
 """
 from __future__ import annotations
 
@@ -18,10 +21,10 @@ from collections import deque
 
 import numpy as np
 
-from bench import traffic, weights
+from bench import families, layout, traffic
 
-__all__ = ["ROOT", "load_cell", "model_dims", "Engine", "run_window",
-           "served_sample", "widest_gap", "DRAIN_CAP_S"]
+__all__ = ["ROOT", "load_cell", "Engine", "run_window", "served_sample",
+           "widest_gap", "DRAIN_CAP_S"]
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "bench")
@@ -29,9 +32,6 @@ BENCH = os.path.join(ROOT, "bench")
 # How long past the window's close requests due in it may take to finish
 # before they count as failed.
 DRAIN_CAP_S = 90.0
-
-_ACT = {"silu": "swiglu", "gelu_pytorch_tanh": "gelu"}
-
 
 def _json(*parts):
     with open(os.path.join(BENCH, *parts)) as f:
@@ -58,46 +58,9 @@ def load_cell(workload: str, bench_json: dict | None = None) -> dict:
     cell["mix"] = _json("traffic", f"{entry['traffic']}.json")
     cell["name"] = workload
     cell["chips"] = entry["chips"]
+    layout.mesh_of(cell)            # refused here, before any set-up, if
+    families.load(cell["conf"])     # the mesh or the family does not hold
     return cell
-
-
-def model_dims(conf: dict) -> dict:
-    """The dims the benchmark's weights, FLOP count and reference use, read
-    from the configuration file's own keys (Hugging Face names)."""
-    H = conf["num_attention_heads"]
-    return {
-        "num_layers": conf["num_hidden_layers"],
-        "d_model": conf["hidden_size"],
-        "num_heads": H,
-        "num_kv_heads": conf["num_key_value_heads"],
-        "head_dim": conf.get("head_dim") or conf["hidden_size"] // H,
-        "d_ff": conf["intermediate_size"],
-        "mlp_type": _ACT[conf["hidden_act"]],
-        "vocab_size": conf["vocab_size"],
-        "rope_theta": float(conf["rope_theta"]),
-        "norm_eps": float(conf.get("rms_norm_eps", conf.get("norm_epsilon"))),
-        "tie_embeddings": bool(conf["tie_word_embeddings"]),
-        "dtype": conf["torch_dtype"],
-    }
-
-
-def program_config(conf: dict, dims: dict):
-    """The program's ``ModelConfig`` for ``conf``, checked against ``dims``
-    so that the program runs what the configuration file states."""
-    from repro.configs import get_config
-    cfg = dataclasses.replace(get_config(conf["arch"]),
-                              **conf.get("program_overrides", {}))
-    got = {"num_layers": cfg.num_layers, "d_model": cfg.d_model,
-           "num_heads": cfg.num_heads, "num_kv_heads": cfg.num_kv_heads,
-           "head_dim": cfg.resolved_head_dim, "d_ff": cfg.d_ff,
-           "mlp_type": cfg.mlp_type, "vocab_size": cfg.vocab_size,
-           "rope_theta": float(cfg.rope_theta), "norm_eps": float(cfg.norm_eps),
-           "tie_embeddings": cfg.tie_embeddings, "dtype": cfg.dtype}
-    bad = {k: (dims[k], got[k]) for k in got if dims[k] != got[k]}
-    if bad or cfg.family != "dense" or cfg.sliding_window or not cfg.use_falcon:
-        raise ValueError(f"program config {cfg.name} departs from the "
-                         f"configuration file: {bad} (file, program)")
-    return cfg
 
 
 class Engine:
@@ -106,18 +69,22 @@ class Engine:
     def __init__(self, cell: dict, seed: int):
         from repro.serve import BucketPolicy, ServeEngine
         self.cell, self.seed = cell, seed
-        self.dims = model_dims(cell["conf"])
-        cfg = program_config(cell["conf"], self.dims)
+        self.family = families.load(cell["conf"])
+        self.dims = self.family.dims(cell["conf"])
+        cfg = self.family.program_config(cell["conf"], self.dims)
+        mesh = layout.mesh_of(cell)
+        self.layout = layout.for_cell(cell)
         mix = cell["mix"]
         policy = BucketPolicy(prefill_seq=tuple(cell["prefill_seq"]),
                               prefill_batch=tuple(cell["prefill_batch"]),
                               decode_batch=tuple(cell["decode_batch"]))
         t = time.perf_counter()
-        params = weights.make(self.dims, seed)
+        params = self.family.make_weights(self.dims, seed, self.layout)
         self.engine = ServeEngine(
             cfg, params=params, max_slots=cell["slots"],
             max_prompt_len=mix["prompt"]["max"],
-            max_new_tokens=mix["output"]["max"], policy=policy)
+            max_new_tokens=mix["output"]["max"], policy=policy,
+            mesh_shape=mesh)
         del params
         self.t_build = time.perf_counter() - t
         if self.engine.max_len != cell["max_len"]:
@@ -158,6 +125,11 @@ class Engine:
         """Drop the program's state so that its device memory is released."""
         self.engine = None
         gc.collect()
+
+    def reference_weights(self):
+        """The weights made again from the seed, in the same layout, for the
+        plain reference."""
+        return self.family.make_weights(self.dims, self.seed, self.layout)
 
 
 class _Step:
@@ -335,18 +307,17 @@ def served_sample(records: list[dict], seed: int, min_tokens: int) -> list[dict]
     return pick
 
 
-def widest_gap(dims: dict, params, sample: list[dict], seq_len: int,
+def widest_gap(family, dims: dict, params, sample: list[dict], seq_len: int,
                max_new: int, controls: tuple = ()) -> dict:
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over every served token of ``sample``.
 
-    Each request runs once through the float32 reference over its prompt and
-    served tokens, right-padded to ``seq_len``. For each precision in
-    ``controls`` the same positions also run through the reference in that
-    precision, and the gap of the token it puts first is read
-    (``control_gap.<precision>``).
+    Each request runs once through the float32 reference of ``family`` over
+    its prompt and served tokens, right-padded to ``seq_len``. For each
+    precision in ``controls`` the same positions also run through the
+    reference in that precision, and the gap of the token it puts first is
+    read (``control_gap.<precision>``).
     """
-    from bench.reference import dense
     worst = 0.0
     worst_ctrl = {c: 0.0 for c in controls}
     n_tok = 0
@@ -357,12 +328,12 @@ def widest_gap(dims: dict, params, sample: list[dict], seq_len: int,
         seq[:len(full)] = full
         pos = [len(prompt) - 1 + j for j in range(len(gen))]
         pos_p = pos + [pos[-1]] * (max_new - len(pos))
-        ref = dense.logits_at(dims, params, seq, pos_p)[:len(gen)]
+        ref = family.logits_at(dims, params, seq, pos_p)[:len(gen)]
         best = ref.max(axis=-1)
         rows = np.arange(len(gen))
         worst = max(worst, float(np.max(best - ref[rows, gen])))
         for c in controls:
-            low = dense.logits_at(dims, params, seq, pos_p, c)[:len(gen)]
+            low = family.logits_at(dims, params, seq, pos_p, c)[:len(gen)]
             gap = best - ref[rows, low.argmax(axis=-1)]
             worst_ctrl[c] = max(worst_ctrl[c], float(np.max(gap)))
         n_tok += len(gen)

@@ -1,7 +1,6 @@
 """Device time per decode step of the programs other than
-``jit_decode_step`` that start inside the engine's ``serve.decode`` span:
-the eager slot-cache gather and scatter, the logits slice (serve engine
-layer)."""
+``jit_decode_step`` that start inside the engine's ``serve.decode`` span,
+such as the logits slice (serve engine layer)."""
 from bench import program_trace as PT
 
 

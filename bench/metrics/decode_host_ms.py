@@ -1,6 +1,6 @@
 """Mean host time of a decode step: the benchmark span around
 ``ServeEngine.step`` minus the device time inside it (serve engine layer:
-eager KV gather and scatter, host argmax, dispatch)."""
+inputs, host argmax, dispatch)."""
 from bench import readers
 
 

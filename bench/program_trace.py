@@ -6,8 +6,7 @@ and keeps what the program itself put there:
 
 - ``spans``: the engine thread's ``serve.*`` spans (``ServeStats.phase``):
   one ``serve.prefill``, ``serve.decode`` or ``serve.verify`` span per step,
-  holding ``serve.inputs``, ``serve.kv_gather``, ``serve.forward``,
-  ``serve.kv_scatter`` and ``serve.sample``;
+  holding ``serve.inputs``, ``serve.forward`` and ``serve.sample``;
 - ``ops``: the device's leaf operations, each with its scope, the HLO
   ``op_name`` of its metadata (``.../mlp/falcon.combine_a/add``). An
   operation that contains others, such as the layer scan's ``while``, is
@@ -15,7 +14,7 @@ and keeps what the program itself put there:
   its root's scope, and a link of an in-place chain under the chain's
   (:func:`_chain_scopes`);
 - ``busy``: the union of the intervals of every device operation;
-- ``modules``: the device's program executions (``jit_gather(12)``).
+- ``modules``: the device's program executions (``jit_decode_step(12)``).
 
 The scope is the ``tf_op`` stat of an operation's event metadata, which
 ``jax.profiler.ProfileData`` does not expose, so the file is read as the
@@ -129,15 +128,11 @@ def _events(pl, ln):
 
 
 def _device_events(space):
-    """The first TPU plane's operations ``(start_ns, dur_ns, scope)`` and
+    """The first device's operations ``(start_ns, dur_ns, scope)`` and
     programs ``[name, start_ns, dur_ns]``; an operation's scope is the
-    ``tf_op`` stat of its event metadata (its HLO ``op_name``)."""
-    for pl in space.planes:
-        if re.fullmatch(r"/device:TPU:\d+", pl.name) and any(
-                ln.name == TR.OPS_LINE for ln in pl.lines):
-            break
-    else:
-        raise ValueError("the trace has no TPU plane with an 'XLA Ops' line")
+    ``tf_op`` stat of its event metadata (its HLO ``op_name``). On a mesh
+    every device runs the same programs, so one device's are read."""
+    pl = TR.device_planes(space.planes, 1)[0]
     stat_id = {e.value.name: e.key for e in pl.stat_metadata}
     names, scopes = {}, {}
     for e in pl.event_metadata:

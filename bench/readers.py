@@ -3,13 +3,12 @@
 A reader gets the run's record (``run``): ``steps``, one entry per engine
 step inside the traced part of the window (its kind, its host span, the
 device time inside that span, the device time of the prefill program, and
-the prompt lengths it prefilled), ``trace`` (the reduced trace), ``dims``
-and ``peaks`` (the device's row of ``bench/peaks.json``). A reader
-that finds nothing to read returns None, and the metric is left out.
+the prompt lengths it prefilled), ``trace`` (the reduced trace), ``cell``,
+``family`` (the configuration's module in ``bench/families``), ``dims``
+and ``peaks`` (one device's row of ``bench/peaks.json``). A reader that
+finds nothing to read returns None, and the metric is left out.
 """
 from __future__ import annotations
-
-from bench import flops
 
 __all__ = ["mean_span_ms", "mean_host_ms", "prefill_mfu", "idle_share"]
 
@@ -34,14 +33,16 @@ def mean_host_ms(run, kind: str):
 
 def prefill_mfu(run):
     """Classical FLOPs of the prompts prefilled, over the prefill program's
-    device time times the bf16 peak, in percent."""
+    device time (on the cell's first device) times the bf16 peak of all the
+    cell's chips, in percent: a share of the whole mesh's peak."""
     st = _of(run, "prefill")
     t = sum(s["prefill_device_s"] for s in st)
     if not st or t <= 0:
         return None
-    work = sum(flops.prefill_flops(run["dims"], n)
+    work = sum(run["family"].prefill_flops(run["dims"], n)
                for s in st for n in s["prompt_lens"])
-    return 100.0 * work / (t * run["peaks"]["bf16_flops_per_s"])
+    peak = run["cell"]["chips"] * run["peaks"]["bf16_flops_per_s"]
+    return 100.0 * work / (t * peak)
 
 
 def idle_share(run):

@@ -74,7 +74,7 @@ def run_cell(bench_json: dict, workload: str, seed: int, seconds: float,
     """Everything a run does once the device is known; returns the result.
     ``cell`` stands in for the files of ``workload`` (tests)."""
     import jax
-    from bench import harness, trace as TR, weights
+    from bench import harness, trace as TR
 
     if cell is None:
         cell = harness.load_cell(workload, bench_json)
@@ -93,16 +93,17 @@ def run_cell(bench_json: dict, workload: str, seed: int, seconds: float,
     w = harness.run_window(eng, seconds, seed, trace_dir=trace_dir,
                            trace_span=span)
     setup_s = w["t0"] - t_start
-    dev = jax.devices()[0]
-    mem = dev.memory_stats() or {}
-    device = dict(device_info, memory_peak_bytes=int(mem.get("peak_bytes_in_use", 0)))
+    devices = jax.devices()[:cell["chips"]]
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devices]
+    device = dict(device_info, count=len(devices), memory_peak_bytes=max(peaks))
 
     recs = w["records"]
     failed = [r for r in recs if r["req"] is None or not r["req"].done]
     open_loop = w["open_loop"]
     if not open_loop:        # in flight at the close of a closed loop: not failed
         failed = [r for r in failed if r["req"] is None]
-    _diagnostics(w, device)
+    _diagnostics(w, peaks)
 
     metrics: dict = {}
     breakdown = None
@@ -118,11 +119,12 @@ def run_cell(bench_json: dict, workload: str, seed: int, seconds: float,
                 metrics[name] = {"value": v, "unit": m["unit"]}
     else:
         xs = TR.find_xplane(trace_dir)
-        ev = TR.from_xspace(xs)
+        ev = TR.from_xspace(xs, cell["chips"])
         red = TR.reduce(ev)
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
-        run = {"cell": cell, "dims": eng.dims, "window": w, "trace": red,
+        run = {"cell": cell, "family": eng.family, "dims": eng.dims,
+               "window": w, "trace": red,
                "steps": TR.join_steps(red, w["steps"]),
                "peaks": load_peaks(device["kind"])}
         for m in bench_json["per_layer"]:
@@ -133,16 +135,17 @@ def run_cell(bench_json: dict, workload: str, seed: int, seconds: float,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         breakdown = {"device_ops": red["top_ops"], "idle_gaps": red["idle_by_span"]}
         log(f"trace: window_s={red['window_s']:.6f} busy_s={red['busy_s']:.6f} "
+            f"busy_s_by_device={json.dumps(red['busy_by_device'])} "
             f"modules={json.dumps(red['modules'])}")
 
     # correctness: free the program's state, then the plain reference
     sample = harness.served_sample(recs, seed, cell["correct"]["min_tokens"])
     del e
     eng.free()
-    params = weights.make(eng.dims, seed)
+    params = eng.reference_weights()
     t = time.perf_counter()
     if sample:
-        chk = harness.widest_gap(eng.dims, params, sample,
+        chk = harness.widest_gap(eng.family, eng.dims, params, sample,
                                  harness.reference_seq_len(cell),
                                  cell["mix"]["output"]["max"])
     else:
@@ -176,7 +179,7 @@ def _end_to_end(name: str, w: dict):
     return None
 
 
-def _diagnostics(w: dict, device: dict) -> None:
+def _diagnostics(w: dict, peaks: list) -> None:
     import numpy as np
     from bench import stats as S
     recs = w["records"]
@@ -205,7 +208,7 @@ def _diagnostics(w: dict, device: dict) -> None:
     span = w["t_stop"] - w["t0"]
     log(f"throughput: tok_s={(d['prompt_tokens'] + d['generated_tokens']) / span:.3f}"
         f" finished={sum(1 for r in recs if r['req'] is not None and r['req'].done)}")
-    log(f"memory: peak_bytes_in_use={device['memory_peak_bytes']}")
+    log(f"memory: peak_bytes_in_use={max(peaks)} by_device={json.dumps(peaks)}")
     steps = [s for s in w["steps"] if s.kind in ("prefill", "decode")]
     for kind in ("prefill", "decode"):
         ms = [1e3 * (s.t1 - s.t0) for s in steps if s.kind == kind]

@@ -13,8 +13,8 @@ for p in (ROOT, os.path.join(ROOT, "src")):
 import pytest  # noqa: E402
 
 TINY_CONF = {
-    "arch": "granite_3_2b", "num_hidden_layers": 2, "hidden_size": 64,
-    "intermediate_size": 128, "num_attention_heads": 4,
+    "arch": "granite_3_2b", "family": "dense", "num_hidden_layers": 2,
+    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 300,
     "hidden_act": "silu", "tie_word_embeddings": True, "rope_theta": 10000.0,
     "torch_dtype": "bfloat16", "rms_norm_eps": 1e-6,
@@ -24,8 +24,7 @@ TINY_CONF = {
 }
 
 
-@pytest.fixture
-def tiny_cell():
+def make_tiny_cell() -> dict:
     """An open-loop cell at a size the CPU serves in seconds."""
     return {
         "name": "tiny.chat", "config": "tiny", "traffic": "chat", "chips": 1,
@@ -37,3 +36,8 @@ def tiny_cell():
                 "prompt": {"median": 20, "sigma": 0.3, "min": 8, "max": 32},
                 "output": {"median": 6, "sigma": 0.4, "min": 3, "max": 12}},
     }
+
+
+@pytest.fixture
+def tiny_cell():
+    return make_tiny_cell()

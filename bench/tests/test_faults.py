@@ -43,9 +43,18 @@ def _alter_tokens(engine):
 
 
 def _state_unchanged(engine):
+    """The decode step returns the cache it was given, unwritten (the real
+    step writes into a copy, so the given cache is not donated)."""
+    import jax
+    import jax.numpy as jnp
     decode = engine._decode_fn
-    engine._decode_fn = lambda params, rows, toks, pos: (
-        decode(params, rows, toks, pos)[0], rows)
+
+    def step(params, cache, slots, toks, pos):
+        logits, _ = decode(params, jax.tree.map(jnp.copy, cache), slots,
+                           toks, pos)
+        return logits, cache
+
+    engine._decode_fn = step
 
 
 def test_a_sound_run_is_correct(tiny_cell, tmp_path):

@@ -6,9 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, weights
-from bench.reference import dense
+from bench import families
 from bench.tests.conftest import TINY_CONF
+
+family = families.load(TINY_CONF)
 
 # float32 against float32, the same mathematics in another order
 F32_TOL = 2e-4
@@ -17,10 +18,10 @@ F32_TOL = 2e-4
 @pytest.fixture(scope="module")
 def setup():
     from repro.configs import get_config
-    dims = harness.model_dims(dict(TINY_CONF, torch_dtype="float32"))
+    dims = family.dims(dict(TINY_CONF, torch_dtype="float32"))
     cfg = dataclasses.replace(get_config("granite_3_2b"),
                               **TINY_CONF["program_overrides"], dtype="float32")
-    params = weights.make(dims, 2 ** 36 + 3)
+    params = family.make_weights(dims, 2 ** 36 + 3)
     toks = np.random.default_rng(0).integers(0, dims["vocab_size"], 24)
     return dims, cfg, params, toks
 
@@ -37,7 +38,7 @@ def _program_logits(cfg, params, toks):
 def test_reference_agrees_with_the_program_at_float32(setup):
     dims, cfg, params, toks = setup
     prog = _program_logits(cfg, params, toks)
-    ref = dense.logits_at(dims, params, toks, list(range(len(toks))))
+    ref = family.logits_at(dims, params, toks, list(range(len(toks))))
     assert ref.shape == prog.shape
     assert np.max(np.abs(ref - prog)) < F32_TOL
 
@@ -45,8 +46,8 @@ def test_reference_agrees_with_the_program_at_float32(setup):
 @pytest.mark.parametrize("low", ["bfloat16", "int8"])
 def test_reference_in_a_lower_precision_misses_the_tolerance(setup, low):
     dims, cfg, params, toks = setup
-    ref = dense.logits_at(dims, params, toks, list(range(len(toks))))
-    lo = dense.logits_at(dims, params, toks, list(range(len(toks))), low)
+    ref = family.logits_at(dims, params, toks, list(range(len(toks))))
+    lo = family.logits_at(dims, params, toks, list(range(len(toks))), low)
     assert np.max(np.abs(lo - ref)) > 10 * F32_TOL
 
 
@@ -54,16 +55,16 @@ def test_right_padding_leaves_earlier_positions_alone(setup):
     dims, _, params, toks = setup
     pad = np.concatenate([toks, np.zeros(8, np.int64)])
     pos = list(range(len(toks)))
-    a = dense.logits_at(dims, params, toks, pos)
-    b = dense.logits_at(dims, params, pad, pos)
+    a = family.logits_at(dims, params, toks, pos)
+    b = family.logits_at(dims, params, pad, pos)
     assert np.max(np.abs(a - b)) < 1e-5
 
 
 def test_weights_depend_on_every_bit_of_the_seed():
-    dims = harness.model_dims(TINY_CONF)
-    a = weights.make(dims, 5)["embed"]
-    b = weights.make(dims, 2 ** 40 + 5)["embed"]
-    c = weights.make(dims, 5)["embed"]
+    dims = family.dims(TINY_CONF)
+    a = family.make_weights(dims, 5)["embed"]
+    b = family.make_weights(dims, 2 ** 40 + 5)["embed"]
+    c = family.make_weights(dims, 5)["embed"]
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     assert np.array_equal(np.asarray(a), np.asarray(c))
     assert a.dtype == jnp.bfloat16

@@ -28,6 +28,30 @@ def test_busy_is_the_union_of_device_operations():
     assert red["busy_s"] == pytest.approx((150 + 60) * 1e-9)  # 100-250, 500-560
 
 
+def test_busy_is_the_mean_over_the_cells_devices():
+    ev = _hand_made()
+    ev.more_ops = [[[100, 300]], [[0, 1000]]]     # 100-400; the whole window
+    red = T.reduce(ev)
+    assert red["busy_by_device"] == pytest.approx([210e-9, 300e-9, 600e-9])
+    assert red["busy_s"] == pytest.approx(370e-9)
+    assert red["steps"] == T.reduce(_hand_made())["steps"]    # first device's
+
+
+def test_prefill_mfu_is_a_share_of_the_whole_meshs_peak():
+    class Family:
+        @staticmethod
+        def prefill_flops(dims, n):
+            return 1e3 * n
+
+    run = {"steps": [{"kind": "prefill", "span_s": 1.0, "busy_s": 1.0,
+                      "prefill_device_s": 1e-6, "prompt_lens": [10]}],
+           "family": Family, "dims": {}, "peaks": {"bf16_flops_per_s": 1e12},
+           "cell": {"chips": 1}}
+    assert readers.prefill_mfu(run) == pytest.approx(1.0)
+    run["cell"]["chips"] = 4
+    assert readers.prefill_mfu(run) == pytest.approx(0.25)
+
+
 def test_device_time_is_grouped_by_program_and_operation():
     red = T.reduce(_hand_made())
     assert red["modules"] == {"jit_chunk_step": pytest.approx(200e-9),
@@ -61,11 +85,7 @@ def test_steps_carry_their_busy_time_and_programs():
             self.kind, self.prompt_lens = kind, lens
 
     steps = T.join_steps(red, [H("prefill", [10]), H("decode", [])])
-    run = {"steps": steps, "trace": red,
-           "dims": {"num_layers": 1, "d_model": 1, "num_heads": 1,
-                    "num_kv_heads": 1, "head_dim": 1, "d_ff": 1,
-                    "mlp_type": "gelu", "vocab_size": 1},
-           "peaks": {"bf16_flops_per_s": 1e12}}
+    run = {"steps": steps, "trace": red}
     assert readers.mean_span_ms(run, "decode") == pytest.approx(200e-6)
     assert readers.mean_host_ms(run, "decode") == pytest.approx(140e-6)
     assert readers.idle_share(run) == pytest.approx(100 * (1 - 210 / 600))

@@ -1,21 +1,26 @@
 """Reduction of a profiler trace to the per-layer numbers of one run.
 
-A trace is read once into :class:`Events`: the device's operations (one
-TPU plane, its ``XLA Ops`` line), the device's program executions (its
-``XLA Modules`` line, named like ``jit_decode_step(12)``), and the host
-spans the benchmark wrote with ``TraceAnnotation`` (names starting with
-``bench.``). Times are nanoseconds on the profiler's common clock.
-:func:`reduce` then gives:
+A trace is read once into :class:`Events`: the operations of the cell's
+first device (its TPU plane's ``XLA Ops`` line), that device's program
+executions (its ``XLA Modules`` line, named like ``jit_decode_step(12)``),
+the host spans the benchmark wrote with ``TraceAnnotation`` (names starting
+with ``bench.``), and the operation intervals of the cell's other devices.
+Times are nanoseconds on the profiler's common clock. :func:`reduce` then
+gives:
 
 - ``window_s``: the traced window, from the first to the last benchmark
   span;
-- ``busy_s``: the union of the device-operation intervals inside it;
+- ``busy_s``: the union of the device-operation intervals inside it, the
+  mean over the cell's devices (``busy_by_device``: each one's);
 - ``modules``: device seconds per program, the numeric suffix dropped;
 - ``top_ops``: the ten operations that took most device time;
 - ``idle_by_span``: device-idle seconds inside the window, by the host span
   that covered each idle moment (``host.none`` where none did);
 - ``steps``: for each ``bench.step#<i>`` span, its length, the device busy
   time inside it, and the device time of each program that ran inside it.
+
+Programs, operations, idle gaps and steps are the first device's: on a mesh
+every device runs the same programs.
 """
 from __future__ import annotations
 
@@ -40,6 +45,8 @@ class Events:
     ops: list        # [name, start_ns, dur_ns]
     modules: list    # [name, start_ns, dur_ns]
     spans: list      # [name, start_ns, dur_ns]
+    # one list of [start_ns, dur_ns] per further device of the cell
+    more_ops: list = dataclasses.field(default_factory=list)
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -50,27 +57,32 @@ def find_xplane(trace_dir: str) -> str:
     return paths[-1]
 
 
-def _device_plane(planes):
-    """The first TPU plane that ran operations."""
-    for pl in planes:
-        if re.fullmatch(r"/device:TPU:\d+", pl.name) and any(
-                ln.name == OPS_LINE for ln in pl.lines):
-            return pl
-    raise ValueError("the trace has no TPU plane with an 'XLA Ops' line: "
-                     + ", ".join(pl.name for pl in planes))
+def device_planes(planes, n: int):
+    """The TPU planes with an ``XLA Ops`` line of the first ``n`` devices,
+    by device number."""
+    tpu = sorted((int(m.group(1)), pl) for pl in planes
+                 if (m := re.fullmatch(r"/device:TPU:(\d+)", pl.name))
+                 and any(ln.name == OPS_LINE for ln in pl.lines))
+    if len(tpu) < n:
+        raise ValueError(f"the trace has {len(tpu)} TPU planes with an "
+                         f"'XLA Ops' line, the cell runs on {n}: "
+                         + ", ".join(pl.name for pl in planes))
+    return [pl for _, pl in tpu[:n]]
 
 
-def from_xspace(path: str) -> Events:
+def from_xspace(path: str, n_devices: int = 1) -> Events:
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
     planes = list(pd.planes)
-    dev = _device_plane(planes)
+    dev, *more = device_planes(planes, n_devices)
     ops, modules, spans = [], [], []
     for ln in dev.lines:
         if ln.name == OPS_LINE:
             ops = [[e.name, e.start_ns, e.duration_ns] for e in ln.events]
         elif ln.name == MODULES_LINE:
             modules = [[e.name, e.start_ns, e.duration_ns] for e in ln.events]
+    more_ops = [[[e.start_ns, e.duration_ns] for ln in pl.lines
+                 if ln.name == OPS_LINE for e in ln.events] for pl in more]
     for pl in planes:
         if not pl.name.startswith("/host:"):
             continue
@@ -78,7 +90,7 @@ def from_xspace(path: str) -> Events:
             spans.extend([e.name, e.start_ns, e.duration_ns] for e in ln.events
                          if e.name.startswith(SPAN_PREFIX))
     spans.sort(key=lambda s: s[1])
-    return Events(ops, modules, spans)
+    return Events(ops, modules, spans, more_ops)
 
 
 def save_json(ev: Events, path: str) -> None:
@@ -121,7 +133,10 @@ def reduce(ev: Events) -> dict:
     lo = min(s[1] for s in ev.spans)
     hi = max(s[1] + s[2] for s in ev.spans)
     busy = _merged(_clip([(s, s + d) for _, s, d in ev.ops], lo, hi))
-    busy_ns = sum(e - s for s, e in busy)
+    busy_ns = [sum(e - s for s, e in busy)]
+    for more in ev.more_ops:
+        iv = _merged(_clip([(s, s + d) for s, d in more], lo, hi))
+        busy_ns.append(sum(e - s for s, e in iv))
 
     modules: dict = {}
     for name, s, d in ev.modules:
@@ -184,7 +199,9 @@ def reduce(ev: Events) -> dict:
             progs[b] = progs.get(b, 0.0) + md * 1e-9
         steps.append({"index": int(name.split("#", 1)[1]), "span_s": d * 1e-9,
                       "busy_s": inside * 1e-9, "programs": progs})
-    return {"window_s": (hi - lo) * 1e-9, "busy_s": busy_ns * 1e-9,
+    return {"window_s": (hi - lo) * 1e-9,
+            "busy_s": sum(busy_ns) / len(busy_ns) * 1e-9,
+            "busy_by_device": [b * 1e-9 for b in busy_ns],
             "modules": modules, "top_ops": top_ops,
             "idle_by_span": idle_by_span, "steps": steps}
 

@@ -6,6 +6,8 @@ The tree has the layout the serve engine takes as ``params``: ``embed``
 makes every leaf in the type it is served in. The benchmark hands the tree
 to the program and, after the window, makes it again from the same seed for
 the plain reference: the reference never reads what the program holds.
+With a ``layout`` (``bench/layout.py``) the same call makes every leaf
+straight into it, with the same values.
 """
 from __future__ import annotations
 
@@ -15,7 +17,7 @@ import json
 import jax
 import jax.numpy as jnp
 
-__all__ = ["padded_vocab", "key_for", "make"]
+__all__ = ["padded_vocab", "key_for", "shapes", "make"]
 
 
 def padded_vocab(vocab: int) -> int:
@@ -31,7 +33,7 @@ def key_for(seed: int):
     return jax.random.fold_in(key, jnp.uint32(s >> 32))
 
 
-def _shapes(c: dict) -> dict:
+def shapes(c: dict) -> dict:
     L, d, hd, f = c["num_layers"], c["d_model"], c["head_dim"], c["d_ff"]
     H, Hkv, Vp = c["num_heads"], c["num_kv_heads"], padded_vocab(c["vocab_size"])
     mlp = {"mlp_up": (L, d, f), "mlp_down": (L, f, d)}
@@ -52,15 +54,14 @@ def _shapes(c: dict) -> dict:
     return tree
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _make(spec: str, key):
+def _build(spec: str, key):
     c = json.loads(spec)
     dt = jnp.dtype(c["dtype"])
-    shapes = _shapes(c)
-    leaves, treedef = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(x, tuple))
+    tree = shapes(c)
+    leaves, treedef = jax.tree.flatten(tree, is_leaf=lambda x: isinstance(x, tuple))
     paths = [jax.tree_util.keystr(p) for p, _ in
              jax.tree_util.tree_flatten_with_path(
-                 shapes, is_leaf=lambda x: isinstance(x, tuple))[0]]
+                 tree, is_leaf=lambda x: isinstance(x, tuple))[0]]
     out = []
     for i, (path, shape) in enumerate(zip(paths, leaves)):
         k = jax.random.fold_in(key, i)
@@ -75,6 +76,18 @@ def _make(spec: str, key):
     return jax.tree.unflatten(treedef, out)
 
 
-def make(c: dict, seed: int):
-    """The weight tree of dims ``c`` (see ``bench.model_dims``) for ``seed``."""
-    return _make(json.dumps(c, sort_keys=True), key_for(seed))
+_make = jax.jit(_build, static_argnums=0)
+
+
+@functools.lru_cache(maxsize=4)
+def _make_in(layout, spec: str):
+    return jax.jit(_build, static_argnums=0,
+                   out_shardings=layout.tree(shapes(json.loads(spec))))
+
+
+def make(c: dict, seed: int, layout=None):
+    """The weight tree of dims ``c`` (``dims`` of ``bench/families/dense.py``)
+    for ``seed``, on the default device, or in ``layout``."""
+    spec = json.dumps(c, sort_keys=True)
+    fn = _make if layout is None else _make_in(layout, spec)
+    return fn(spec, key_for(seed))
