@@ -19,13 +19,14 @@ import numpy as np
 
 from repro.core import engine
 from repro.core.falcon_gemm import FalconConfig, falcon_dense
-from repro.parallel.sharding import BATCH, shard_act
+from repro.parallel.sharding import BATCH, shard_act, slot_cache_sharding
 from repro.configs.base import ModelConfig
 from . import layers as L
 from . import moe as MOE
 from . import ssd as SSD
 
-__all__ = ["init_params", "forward", "init_cache", "falcon_config_for",
+__all__ = ["init_params", "forward", "init_cache", "init_slot_cache",
+           "falcon_config_for",
            "chunked_xent", "lm_loss"]
 
 
@@ -130,6 +131,21 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None) -> dict:
         cache["state"] = jnp.zeros(
             (Lc, batch, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim), dt)
     return cache
+
+
+def init_slot_cache(cfg: ModelConfig, slots: int, max_len: int, mesh=None):
+    """A serve engine's slot cache and its shardings (None without a mesh).
+
+    Over a mesh the cache is made in place in ``slot_cache_sharding``'s
+    layout (K and V by KV head over "model"), never whole on one device.
+    """
+    def make():
+        return init_cache(cfg, slots, max_len)
+
+    if mesh is None:
+        return make(), None
+    sharding = slot_cache_sharding(jax.eval_shape(make), mesh)
+    return jax.jit(make, out_shardings=sharding)(), sharding
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import compat
 
 __all__ = ["ShardingRules", "batch_axes", "param_sharding", "activation_specs",
-           "named_sharding", "make_rules", "layouts_for_mesh"]
+           "named_sharding", "make_rules", "slot_cache_sharding",
+           "layouts_for_mesh"]
 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -129,6 +130,18 @@ def param_sharding(params_shape: Any, mesh: Mesh, rules: ShardingRules):
         for path, leaf in flat
     ]
     return jax.tree_util.tree_unflatten(tree, specs)
+
+
+def slot_cache_sharding(cache_shape: Any, mesh: Mesh):
+    """Shardings of a serve engine's slot cache: ``k``/``v`` leaves
+    ``(L, slots, S, Hkv, hd)`` over "model" on their KV-head axis, so that
+    each device keeps the K/V of the query heads it computes; replicated
+    where the axis size does not divide Hkv (``spec_for``'s rule), as is a
+    recurrent ``state`` leaf."""
+    rules = ShardingRules(
+        ((r"^[kv]$", (None, None, None, "model", None)),), batch_axes(mesh),
+        tuple((name, int(mesh.shape[name])) for name in mesh.axis_names))
+    return param_sharding(cache_shape, mesh, rules)
 
 
 def named_sharding(mesh: Mesh, *spec) -> NamedSharding:

@@ -94,8 +94,10 @@ def on_slots(step, layouts):
     engine donates the cache). ``layouts`` (the cache leaves' device
     layouts) pins the gathered rows to the cache's own layout: left free,
     the TPU compiler builds them in a padded layout and copies them twice.
-    The function keeps ``step``'s name, so its program keeps the name a
-    device trace reads.
+    Under a mesh they are None: the constraint does not partition, and
+    GSPMD would gather sharded rows whole onto every device for it (the
+    TPU compiler gives the same program either way). The function keeps
+    ``step``'s name, so its program keeps the name a device trace reads.
     """
     def run(params, cache, slots, *args):
         n = jax.tree.leaves(cache)[0].shape[1]
@@ -104,10 +106,15 @@ def on_slots(step, layouts):
         # slice starts clamp into range: with no real row, pads read the
         # last slot
         read = jnp.where(slots < n, slots, slots[jnp.maximum(k - 1, 0)])
-        rows = jax.tree.map(
-            lambda c, lay: with_layout_constraint(jnp.concatenate(
+
+        def gather(c, lay=None):
+            r = jnp.concatenate(
                 [lax.dynamic_slice_in_dim(c, read[i], 1, axis=1)
-                 for i in range(b)], axis=1), lay), cache, layouts)
+                 for i in range(b)], axis=1)
+            return r if lay is None else with_layout_constraint(r, lay)
+
+        rows = (jax.tree.map(gather, cache) if layouts is None
+                else jax.tree.map(gather, cache, layouts))
         logits, rows = step(params, rows, *args)
 
         def put(i, cache):
@@ -146,10 +153,11 @@ class ServeEngine:
     ``mesh_shape={"data": d, "model": m}`` spanning more than one device
     lifts the engine onto a real mesh: weights shard tensor-parallel by the
     ``parallel.sharding`` rule table (offline Combine B then runs on sharded
-    weights), the KV cache stays replicated (decode activations gather back
-    each step — "replicated-then-gathered"), and every jitted step runs under
-    the mesh context so FalconGEMM's shard-aware plans and ``shard_act``
-    constraints see it. Simulate devices on one host with
+    weights), the slot cache's K and V shard by KV head over "model" where
+    the axis size divides the KV heads and are replicated otherwise
+    (``parallel.sharding.slot_cache_sharding``), and every jitted step runs
+    under the mesh context so FalconGEMM's shard-aware plans and
+    ``shard_act`` constraints see it. Simulate devices on one host with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
 
@@ -229,7 +237,8 @@ class ServeEngine:
                 self.draft = SelfDraft(model_cfg, self.params,
                                        max_slots=max_slots,
                                        max_len=self.max_len,
-                                       keep_layers=draft_keep_layers)
+                                       keep_layers=draft_keep_layers,
+                                       mesh=self.mesh)
             self.n_precombined = 0
             if precombine:
                 # Offline Combine B priced at the largest prefill bucket M;
@@ -249,27 +258,34 @@ class ServeEngine:
         self.requests: list[Request] = []
         self.prefix = RadixPrefixCache(max_entries=prefix_entries) \
             if prefix_cache else None
-        self.cache = M.init_cache(model_cfg, max_slots, self.max_len)
-        if self.mesh is not None:
-            # Replicated-then-gathered decode: the KV cache lives replicated on
-            # every device; each step's projections run tensor-parallel and the
-            # (small) per-step activations gather back before the cache write.
-            self.cache = jax.device_put(
-                self.cache, NamedSharding(self.mesh, P()))
+        # Under a mesh each device holds the K/V of its own KV heads, beside
+        # the query heads that read them: the cache write and the attention
+        # read stay local.
+        self.cache, self.cache_sharding = M.init_slot_cache(
+            model_cfg, max_slots, self.max_len, self.mesh)
         self.pos = np.zeros(max_slots, np.int32)   # per-slot next write index
         # Each step is one program that takes the slot cache (donated, so
-        # its write-back is in place) and returns it. Under a mesh, its
-        # outputs (logits, cache) come back replicated like the cache itself:
-        # left to GSPMD, a KV-head dim that the model axis does not divide
-        # comes back partially tiled, and the cache would not alias.
-        out = None if self.mesh is None else NamedSharding(self.mesh, P())
-        layouts = jax.tree.map(lambda c: c.format.layout, self.cache)
+        # its write-back is in place) and returns it. Under a mesh, the
+        # cache comes back in its own sharding, so that it aliases, and the
+        # logits replicated.
+        if self.mesh is None:
+            out = None
+            layouts = jax.tree.map(lambda c: c.format.layout, self.cache)
+        else:
+            out, layouts = (NamedSharding(self.mesh, P()),
+                            self.cache_sharding), None
         self._prefill_fn, self._decode_fn, self._verify_fn = (
             jax.jit(on_slots(make(model_cfg), layouts), donate_argnums=1,
                     out_shardings=out)
             for make in (make_chunk_prefill_step, make_decode_step,
                          make_verify_step))
+        # the jitted step programs (the draft's too), whose executables
+        # ``stats.live_compiles`` counts
+        self._programs_counted = (self._prefill_fn, self._decode_fn,
+                                  self._verify_fn,
+                                  *getattr(self.draft, "programs", ()))
         self._compiled: set[tuple] = set()          # step shapes already traced
+        self._warm_programs = 0     # step executables when warm() returned
         self._submit_lock = threading.Lock()
 
     # -- mesh ----------------------------------------------------------------
@@ -370,8 +386,11 @@ class ServeEngine:
         2. Each (phase, shape) step function — prefill chunks, decode or
            verify rounds, and the draft's own steps — is traced and compiled
            once on zero inputs, so no live request ever pays a compile. The
-           warm steps run against the real slot cache with every slot index
-           out of range, so they write nothing.
+           inputs are made as a live step makes them (host arrays through
+           :meth:`_step`), so that the programs compiled here are the ones
+           live steps call; ``stats.live_compiles`` counts any compiled
+           later. The warm steps run against the real slot cache with every
+           slot index out of range, so they write nothing.
         """
         t0 = time.perf_counter()
         grid = (list(self.policy.prefill_shapes())
@@ -382,38 +401,48 @@ class ServeEngine:
                 dtype=str(self.cfg.dtype), mesh_shape=self.mesh_shape,
                 kv_len=self.max_len, spec_gamma=self.gamma or None)
             for (b, s) in self.policy.prefill_shapes():
-                z = jnp.zeros((b,), jnp.int32)
+                z = np.zeros((b,), np.int32)
                 self._step(self._prefill_fn, self._pad_slots(b),
-                           jnp.zeros((b, s), jnp.int32), z, z)
+                           np.zeros((b, s), np.int32), z, z)
                 self._compiled.add(("prefill", b, s))
             for b in self.policy.decode_batch:
                 fn, key, s = (self._verify_fn, "spec", self.gamma + 1) \
                     if self.gamma else (self._decode_fn, "decode", 1)
-                self._step(fn, self._pad_slots(b),
-                           jnp.zeros((b, s), jnp.int32),
-                           jnp.zeros((b,), jnp.int32))
+                self._step(fn, self._pad_slots(b), np.zeros((b, s), np.int32),
+                           np.zeros((b,), np.int32))
                 self._compiled.add((key, b))
             if self.draft is not None:
                 self.draft.warm(self.policy, self.gamma)
+        self._warm_programs = self._programs()
+        self.stats.live_compiles = 0
         self.stats.warm_plans = n_plans
         self.stats.warmed_shapes = len(self._compiled)
         self.stats.t_warm = time.perf_counter() - t0
         return {"plans": n_plans, "shapes": len(self._compiled),
                 "seconds": self.stats.t_warm}
 
-    def _pad_slots(self, b: int, slots=()) -> jax.Array:
+    def _pad_slots(self, b: int, slots=()) -> np.ndarray:
         """The (b,) slot indices of a micro-batch: its slots, then pad rows
         at the out-of-range index ``max_slots``, which write nothing."""
         idx = np.full((b,), self.max_slots, np.int32)
         idx[:len(slots)] = slots
-        return jnp.asarray(idx)
+        return idx
 
     def _step(self, fn, slots, *args):
-        """Run one jitted step on the slot cache; keep the cache it returns
-        (the old one was donated) and return the logits, on the host's
-        clock: the step has finished when this returns."""
-        logits, self.cache = fn(self.params, self.cache, slots, *args)
+        """Run one jitted step on the slot cache, its host-array inputs
+        made device arrays here; keep the cache it returns (the old one was
+        donated) and return the logits, on the host's clock: the step has
+        finished when this returns."""
+        logits, self.cache = fn(self.params, self.cache,
+                                *map(jnp.asarray, (slots, *args)))
         return jax.block_until_ready(logits)
+
+    def _programs(self) -> int:
+        """Executables compiled so far by the jitted step programs (the
+        draft's too), from each ``jax.jit`` function's own executable cache
+        (``_cache_size``, private to JAX: if a JAX release drops it, this
+        raises rather than count nothing)."""
+        return sum(f._cache_size() for f in self._programs_counted)
 
     # -- step loop -----------------------------------------------------------
 
@@ -442,6 +471,7 @@ class ServeEngine:
                     "rids": " ".join(str(r.rid) for r in work.requests)}
         with self.stats.phase(kind, **meta):
             run(work)
+        self.stats.live_compiles = self._programs() - self._warm_programs
         return True
 
     def run(self, max_steps: int | None = None) -> list[Request]:
@@ -486,8 +516,8 @@ class ServeEngine:
             with phase("forward"):
                 # pad rows write nothing; pad positions inside a row are
                 # overwritten by decode before the validity mask admits them
-                logits = self._step(self._prefill_fn, slots, jnp.asarray(toks),
-                                    jnp.asarray(start), jnp.asarray(last))
+                logits = self._step(self._prefill_fn, slots, toks, start,
+                                    last)
             if self.draft is not None:
                 with phase("draft"):
                     self.draft.prefill_chunk(toks, start, last, work.slots, k)
@@ -562,8 +592,7 @@ class ServeEngine:
                 toks[i, 0] = r.generated[-1]
                 pos[i] = self.pos[work.slots[i]]
         with falcon.use(self.fcfg), self._mesh_ctx(), phase("forward"):
-            logits = self._step(self._decode_fn, slots, jnp.asarray(toks),
-                                jnp.asarray(pos))
+            logits = self._step(self._decode_fn, slots, toks, pos)
         with phase("sample"):
             step_logits = np.asarray(logits[:, -1])
             self.stats.decode_steps += 1
@@ -605,8 +634,7 @@ class ServeEngine:
                 drafts = self.draft.propose(idx, last2, pos, g, k)   # (b, γ)
                 verify = np.concatenate([last2[:, 1:], drafts], axis=1)
             with phase("forward"):
-                logits = self._step(self._verify_fn, slots,
-                                    jnp.asarray(verify), jnp.asarray(pos))
+                logits = self._step(self._verify_fn, slots, verify, pos)
         with phase("sample"):
             logits_np = np.asarray(logits)                       # (b, γ+1, V)
             greedy = np.argmax(logits_np, axis=-1)

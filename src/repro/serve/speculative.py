@@ -36,6 +36,7 @@ from typing import Any, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import model as M
 from repro.train.steps import make_chunk_prefill_step, make_decode_step
@@ -93,7 +94,7 @@ class SelfDraft:
     """
 
     def __init__(self, model_cfg, params, *, max_slots: int, max_len: int,
-                 keep_layers: int | None = None):
+                 keep_layers: int | None = None, mesh=None):
         keep = int(keep_layers or model_cfg.num_layers)
         if not 1 <= keep <= model_cfg.num_layers:
             raise ValueError(
@@ -107,16 +108,23 @@ class SelfDraft:
             self.params["layers"] = jax.tree.map(
                 lambda p: p[:keep], params["layers"])
         self.max_len = max_len
-        self.cache = M.init_cache(self.cfg, max_slots, max_len)
-        self._chunk_fn = jax.jit(make_chunk_prefill_step(self.cfg))
-        self._decode_fn = jax.jit(make_decode_step(self.cfg))
+        # over a mesh the slot cache, and the rows each step returns, keep
+        # the target's layout, as ``mesh`` gives it
+        self.cache, sharding = M.init_slot_cache(self.cfg, max_slots, max_len,
+                                                 mesh)
+        out = None if mesh is None else (NamedSharding(mesh, P()), sharding)
+        self._chunk_fn = jax.jit(make_chunk_prefill_step(self.cfg),
+                                 out_shardings=out)
+        self._decode_fn = jax.jit(make_decode_step(self.cfg),
+                                  out_shardings=out)
+        # the jitted steps, whose executables the engine counts
+        self.programs = (self._chunk_fn, self._decode_fn)
 
     # -- prefill mirror ------------------------------------------------------
 
     def prefill_chunk(self, tokens, start, last, slots, k) -> None:
         B = tokens.shape[0]
-        idx = jnp.asarray(list(slots) + [slots[-1]] * (B - k))
-        rows = jax.tree.map(lambda c: c[:, idx], self.cache)
+        rows = self._rows(jnp.asarray(list(slots) + [slots[-1]] * (B - k)))
         logits, new_rows = self._chunk_fn(
             self.params, rows, jnp.asarray(tokens), jnp.asarray(start),
             jnp.asarray(last))
@@ -131,25 +139,31 @@ class SelfDraft:
     def propose(self, slot_idx, last2, pos, gamma: int, k: int) -> np.ndarray:
         """Catch-up ``(B, 2)`` forward, then γ-1 single-token greedy steps."""
         idx = jnp.asarray(slot_idx)
-        pos = jnp.asarray(pos)
-        rows = jax.tree.map(lambda c: c[:, idx], self.cache)
+        rows = self._rows(idx)
+        # positions and tokens move on the host, so that each step's inputs
+        # are made as warm()'s are
+        pos = np.asarray(pos)
         # catch-up: re-feed [t_prev, t_last] at pos-1; rewriting the cached
         # position pos-1 is idempotent, and this repairs the draft KV after
         # any acceptance count of the previous round with one fixed shape
         logits, rows = self._decode_fn(
-            self.params, rows, jnp.asarray(last2), pos - 1)
-        out = [np.argmax(np.asarray(logits[:, -1]), axis=-1)]
-        p = pos + 1
-        for _ in range(gamma - 1):
+            self.params, rows, jnp.asarray(last2), jnp.asarray(pos - 1))
+        out = [np.argmax(np.asarray(logits[:, -1]), axis=-1).astype(np.int32)]
+        for j in range(1, gamma):
             logits, rows = self._decode_fn(
-                self.params, rows, jnp.asarray(out[-1][:, None], jnp.int32), p)
-            out.append(np.argmax(np.asarray(logits[:, -1]), axis=-1))
-            p = p + 1
+                self.params, rows, jnp.asarray(out[-1][:, None]),
+                jnp.asarray(pos + j))
+            out.append(np.argmax(np.asarray(logits[:, -1]),
+                                 axis=-1).astype(np.int32))
         real = idx[:k]
         self.cache = jax.tree.map(
             lambda c, nc: c.at[:, real].set(nc[:, :k].astype(c.dtype)),
             self.cache, rows)
-        return np.stack(out, axis=1).astype(np.int32)
+        return np.stack(out, axis=1)
+
+    def _rows(self, idx):
+        """The cache rows of slots ``idx`` (B,)."""
+        return jax.tree.map(lambda c: c[:, idx], self.cache)
 
     # -- prefix-cache payloads ----------------------------------------------
 
@@ -172,27 +186,25 @@ class SelfDraft:
 
     def warm(self, policy, gamma: int, params_like=None) -> int:
         """Compile the draft's chunk-prefill, catch-up and single-token
-        shapes on zeros so no live round pays a trace."""
+        shapes on zeros so no live round pays a trace. The zeros are host
+        arrays made device arrays as live rounds make theirs, so the
+        programs compiled are the ones live rounds call."""
+        def zeros(*shape):
+            return jnp.asarray(np.zeros(shape, np.int32))
+
         n = 0
         for (b, s) in policy.prefill_shapes():
-            rows = jax.tree.map(
-                lambda c: jnp.broadcast_to(
-                    c[:, :1], (c.shape[0], b) + c.shape[2:]), self.cache)
+            rows = self._rows(zeros(b))
             jax.block_until_ready(self._chunk_fn(
-                self.params, rows, jnp.zeros((b, s), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32)))
+                self.params, rows, zeros(b, s), zeros(b), zeros(b)))
             n += 1
         for b in policy.decode_batch:
-            rows = jax.tree.map(
-                lambda c: jnp.broadcast_to(
-                    c[:, :1], (c.shape[0], b) + c.shape[2:]), self.cache)
+            rows = self._rows(zeros(b))
             jax.block_until_ready(self._decode_fn(
-                self.params, rows, jnp.zeros((b, 2), jnp.int32),
-                jnp.zeros((b,), jnp.int32)))
+                self.params, rows, zeros(b, 2), zeros(b)))
             if gamma > 1:
                 jax.block_until_ready(self._decode_fn(
-                    self.params, rows, jnp.zeros((b, 1), jnp.int32),
-                    jnp.zeros((b,), jnp.int32)))
+                    self.params, rows, zeros(b, 1), zeros(b)))
                 n += 1
             n += 1
         return n

@@ -67,6 +67,9 @@ class ServeStats:
     # eager programs the engine dispatches against the slot cache outside
     # its step programs: one per prefix-cache load, none otherwise
     kv_eager_writes: int = 0
+    # step programs (the draft's too) first compiled after warm() returned:
+    # 0 when warm-up built its inputs as live steps build theirs
+    live_compiles: int = 0
     # warmup provenance
     warmed_shapes: int = 0
     warm_plans: int = 0

@@ -85,9 +85,9 @@ def test_served_tokens_match_eager_decode(case):
 
 
 def test_mesh_engine_matches_eager_decode_and_donates():
-    """Two simulated devices, model axis 2: the replicated cache goes in
-    donated and comes back with its sharding, and the served tokens and
-    logits match eager decode."""
+    """Two simulated devices, model axis 2: the cache, sharded by KV head
+    (4 KV heads over the axis of 2), goes in donated and comes back with its
+    sharding, and the served tokens and logits match eager decode."""
     out = run_multidevice(f"""
         import sys
         sys.path.insert(0, {REPO!r})
@@ -110,9 +110,9 @@ def test_mesh_engine_matches_eager_decode_and_donates():
         assert eng.step()
         assert all(c.is_deleted() for c in old)
         eng.run()
-        repl = NamedSharding(eng.mesh, P())
+        heads = NamedSharding(eng.mesh, P(None, None, None, "model", None))
         for c in jax.tree.leaves(eng.cache):
-            assert c.sharding.is_equivalent_to(repl, c.ndim), c.sharding
+            assert c.sharding.is_equivalent_to(heads, c.ndim), c.sharding
         for r in reqs:
             assert_matches_reference(eng, r)
         print("MESH_OK", len(reqs), eng.stats.kv_eager_writes)
@@ -227,3 +227,20 @@ def test_kv_eager_writes_counts_prefix_loads(prefix_cache):
     assert s["kv_eager_writes"] == (2 if prefix_cache else 0)
     assert s["kv_eager_writes"] == s["prefix_hits"]
     assert "kv_gather" not in s["phase_s"] and "kv_scatter" not in s["phase_s"]
+
+
+@pytest.mark.parametrize("warm", [True, False], ids=["warmed", "unwarmed"])
+def test_live_compiles_counts_the_step_programs_compiled_after_warm(warm):
+    """The counter reads 0 after ``warm()`` and, as its positive control,
+    one executable per step shape on an engine that serves unwarmed (each
+    shape is then a bucket miss, compiled live); ``summary()`` reports it."""
+    eng = ServeEngine(CFG, max_slots=4, max_prompt_len=16, max_new_tokens=4,
+                      seed=0)
+    if warm:
+        eng.warm()
+    _serve(eng, _waves("padded_decode", np.random.default_rng(11)))
+    s = eng.summary()
+    if warm:
+        assert s["live_compiles"] == s["bucket_misses"] == 0
+    else:
+        assert s["live_compiles"] == s["bucket_misses"] >= 2

@@ -263,8 +263,8 @@ def test_serve_stats_as_dict_keys_are_stable():
         "decode_emitted_tokens", "prefill_padded_tokens",
         "decode_padded_tokens", "drafted_tokens", "accepted_tokens",
         "prefix_hits", "prefix_misses", "prefix_tokens_reused",
-        "bucket_hits", "bucket_misses", "kv_eager_writes", "warmed_shapes",
-        "warm_plans",
+        "bucket_hits", "bucket_misses", "kv_eager_writes", "live_compiles",
+        "warmed_shapes", "warm_plans",
         "t_warm", "t_prefill", "t_decode", "phase_s", "phase_max_s",
         "requests_admitted",
         "requests_finished", "bucket_hit_rate", "padding_waste",

@@ -4,7 +4,8 @@ Nothing runs: each test lowers a kernel at granite_3_2b widths (d_model
 2048, d_ff 8192, vocab 49155) and compiles it for one chip of a described
 ``v5e:2x2`` topology. What the chip's compiler refuses -- a block that is
 not tile-aligned, too much VMEM -- fails here without a chip. Interpret-mode
-tests cannot see either.
+tests cannot see either. The serve engine's step programs compile here too,
+on one chip and over all four as a ``{"data": 1, "model": 4}`` mesh.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every test file.
@@ -31,8 +32,8 @@ M_PREFILL, D_MODEL, D_FF, VOCAB = 1024, 2048, 8192, 49155
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent cache off.
+def v5e_2x2():
+    """A described v5e:2x2, with the persistent cache off.
 
     A compile for a described chip is written to the persistent cache but
     cannot be read back without a chip, so the cache is off around these.
@@ -51,10 +52,22 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(v5e_2x2):
+    """The four chips as a ``{"data": 1, "model": 4}`` mesh."""
+    from jax.sharding import Mesh
+    return Mesh(np.array(v5e_2x2.devices).reshape(1, 4), ("data", "model"))
 
 
 def _compile(fn, sharding, *shapes):
@@ -192,3 +205,47 @@ def test_slot_step_writes_the_donated_cache_in_place(one_chip):
                             header))
     n = len(jax.tree.leaves(params))
     assert pairs == {"1": str(n), "2": str(n + 1)}, pairs   # k, v
+
+
+def test_tp4_decode_step_keeps_the_slot_cache_sharded_by_kv_head(four_chips):
+    """The serve engine's decode step for starcoder2-15b's widths (2 of its
+    40 layers) over four described chips, as ``ServeEngine`` builds it under
+    a mesh: each device holds a quarter of the slot cache (its KV head),
+    which is donated and aliased, and no cache rows are gathered; the
+    row-parallel projections all-reduce."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.models import model as M
+    from repro.parallel import sharding as SH
+    from repro.serve.engine import on_slots
+    from repro.train.steps import make_decode_step
+
+    mesh = four_chips
+    cfg = dataclasses.replace(get_config("starcoder2_15b"), num_layers=2)
+    slots, max_len, b = 8, 2048, 8
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    pshape = jax.eval_shape(M.init_params, cfg, jax.random.PRNGKey(0))
+    cshape = jax.eval_shape(lambda: M.init_cache(cfg, slots, max_len))
+    csh = SH.slot_cache_sharding(cshape, mesh)
+    params = placed(pshape, SH.param_sharding(pshape, mesh, SH.make_rules(mesh)))
+    cache = placed(cshape, csh)
+    repl = NamedSharding(mesh, P())
+    step = jax.jit(on_slots(make_decode_step(cfg), None), donate_argnums=1,
+                   out_shardings=(repl, csh))
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=repl)
+            for s in ((b,), (b, 1), (b,))]
+    with jax.set_mesh(mesh), falcon.use(M.falcon_config_for(cfg, dict(mesh.shape))):
+        compiled = step.lower(params, cache, *args).compile()
+    txt = compiled.as_text()
+    whole = sum(c.size * c.dtype.itemsize for c in jax.tree.leaves(cshape))
+    assert compiled.memory_analysis().alias_size_in_bytes == whole // 4
+    gathered = re.findall(r"= \w+\[([\d,]*)\]\S* all-gather(?:-start)?\(", txt)
+    assert all(len(s.split(",")) < 5 for s in gathered), gathered
+    assert re.search(r" all-reduce(-start)?\(", txt)
