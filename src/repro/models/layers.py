@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.core import engine
 from repro.core.falcon_gemm import FalconConfig, falcon_dense
-from repro.parallel.sharding import BATCH, shard_act
+from repro.parallel.sharding import BATCH, kv_layout, shard_act
 
 # ---------------------------------------------------------------------------
 # Param helpers
@@ -72,45 +72,44 @@ def attention_scores(q, k, v, qpos, kpos, window, kv_valid=None,
                      ragged: bool = False):
     """Direct attention. q: (B,Sq,H,hd) k,v: (B,Sk,Hkv,hd) -> (B,Sq,H,hd).
 
-    GQA is realized by repeating K/V up to H heads rather than grouping Q
-    down to Hkv: the full H dim stays intact so its "model"-axis sharding
-    survives (grouping H -> (Hkv, rep) with Hkv < model-parallelism would
-    force XLA to replicate the (B,H,Sq,Sk) score tensor — catastrophic at
-    32k context).
+    GQA groups Q onto the KV heads: q becomes (B,Sq,Hkv,rep,hd) and each KV
+    head contracts against its own ``rep`` query heads, so K/V are read at
+    their own size and never copied. Under a mesh whose "model" axis does
+    not divide Hkv, K/V are first repeated up to H heads, so the query
+    heads keep their sharding (``sharding.kv_layout`` is the rule).
 
     ``ragged=True`` builds the mask per batch row (positions differ across
     the batch — continuous-batching decode where every slot sits at its own
-    offset). The uniform path keeps the (Sq, Sk) mask so 32k-context cells
-    never materialize a per-batch mask they don't need.
+    offset). The uniform path keeps one (Sq, Sk) mask for the whole batch.
     """
     B, Sq, H, hd = q.shape
+    if kv_layout(H, k.shape[2]) == "repeat":
+        rep = H // k.shape[2]
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     Hkv = k.shape[2]
-    rep = H // Hkv
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    logits = engine.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    q = shard_act(q.reshape(B, Sq, Hkv, H // Hkv, hd), BATCH, None, "model")
+    logits = engine.einsum("bqgrd,bkgd->bgrqk", q, k).astype(jnp.float32)
     logits *= 1.0 / np.sqrt(hd)
     if ragged:
         m = jax.vmap(lambda qp, kp: _mask(qp, kp, window))(qpos, kpos)
         if kv_valid is not None:
             m = m & kv_valid[:, None, :]
-        logits = jnp.where(m[:, None], logits, NEG_INF)
+        logits = jnp.where(m[:, None, None], logits, NEG_INF)
     else:
         m = _mask(qpos[0], kpos[0], window)  # positions identical across batch
         if kv_valid is not None:
             m = m & kv_valid[0][None, :]
-        logits = jnp.where(m[None, None], logits, NEG_INF)
+        logits = jnp.where(m[None, None, None], logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    return engine.einsum("bhqk,bkhd->bqhd", p, v)
+    return engine.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(B, Sq, H, hd)
 
 
 def flash_attention(q, k, v, qpos, kpos, window, kv_valid=None,
                     q_chunk: int = 512, ragged: bool = False):
     """Memory-bounded attention: scan over query chunks.
 
-    Keeps the score tensor at (B, H, q_chunk, Sk) — required to compile the
-    32k/500k cells without materializing S^2 scores.
+    Keeps the score tensor at (B, H, q_chunk, Sk), so a long prompt never
+    materializes S^2 scores.
     """
     B, Sq, H, hd = q.shape
     if Sq <= q_chunk:

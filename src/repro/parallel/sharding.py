@@ -26,7 +26,7 @@ from repro import compat
 
 __all__ = ["ShardingRules", "batch_axes", "param_sharding", "activation_specs",
            "named_sharding", "make_rules", "slot_cache_sharding",
-           "layouts_for_mesh"]
+           "layouts_for_mesh", "kv_layout"]
 
 
 def batch_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -244,6 +244,26 @@ def shard_act(x, *spec):
     spec = tuple(spec) + (None,) * (x.ndim - len(spec))
     fspec = P(*[filt(a, d) for a, d in zip(spec, x.shape)])
     return jax.lax.with_sharding_constraint(x, fspec)
+
+
+def kv_layout(num_heads: int, num_kv_heads: int) -> str:
+    """How grouped-query attention meets its K/V heads under the active mesh.
+
+    ``"grouped"``: each KV head contracts against its own group of
+    ``num_heads // num_kv_heads`` query heads, so K/V are never copied. That
+    keeps the head dim sharded where the "model" axis that ``shard_act``
+    would apply to a head dim divides the KV heads (always without a mesh,
+    and under "fsdp_only", where heads are not model-sharded).
+    ``"repeat"``: K/V are repeated up to ``num_heads``, so the query heads
+    keep their own sharding where the axis does not divide the KV heads.
+    """
+    shards = 1
+    mesh = compat.get_abstract_mesh()
+    if mesh is not None and _STYLE_CTX.get() != "fsdp_only":
+        shards = int(dict(mesh.shape).get("model", 1))
+    if num_heads == num_kv_heads or num_kv_heads % shards == 0:
+        return "grouped"
+    return "repeat"
 
 
 def activation_specs(rules: ShardingRules) -> dict[str, P]:

@@ -229,6 +229,9 @@ class ServeEngine:
             elif shard is not None:
                 params = jax.device_put(params, shard(params))
             self.params = params
+            # how attention meets the K/V heads in this engine's programs
+            self.attn_kv_layout = SH.kv_layout(model_cfg.num_heads,
+                                               model_cfg.num_kv_heads)
             self.draft: DraftModel | None = draft
             if self.gamma and self.draft is None:
                 # built from RAW params: a layer slice of a precombined tree
@@ -692,6 +695,7 @@ class ServeEngine:
         d["mesh"] = self.mesh_shape or None
         d["n_devices"] = (1 if self.mesh is None
                           else int(np.prod(list(dict(self.mesh.shape).values()))))
+        d["attn_kv_layout"] = self.attn_kv_layout
         return d
 
 

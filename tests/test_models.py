@@ -137,3 +137,139 @@ def test_param_counts_match_literature():
         sds = _jax.eval_shape(lambda c=cfg: MM.init_params(c, _jax.random.PRNGKey(0)))
         n = sum(int(np.prod(l.shape)) for l in _jax.tree.leaves(sds))
         assert lo <= n <= hi, f"{arch}: {n:.3e} outside [{lo:.1e},{hi:.1e}]"
+
+
+def _repeated_attention(q, k, v, qpos, kpos, window, kv_valid=None,
+                        ragged=False):
+    """GQA by repeating K/V up to the query heads: the reference form."""
+    from repro.models.layers import NEG_INF, _mask
+    hd = q.shape[-1]
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    logits *= 1.0 / np.sqrt(hd)
+    if ragged:
+        m = jax.vmap(lambda qp, kp: _mask(qp, kp, window))(qpos, kpos)
+        if kv_valid is not None:
+            m = m & kv_valid[:, None, :]
+        logits = jnp.where(m[:, None], logits, NEG_INF)
+    else:
+        m = _mask(qpos[0], kpos[0], window)
+        if kv_valid is not None:
+            m = m & kv_valid[0][None, :]
+        logits = jnp.where(m[None, None], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("ragged", [False, True], ids=["uniform", "ragged"])
+@pytest.mark.parametrize("rep", [1, 4, 12])
+def test_grouped_attention_matches_the_repeated_form(rep, ragged, dtype, rng):
+    """Each KV head contracting against its own query group equals K/V
+    repeated up to the query heads, up to summation order: full causal, a
+    sliding window, a cache with ``kv_valid``, and (through
+    ``flash_attention``) a query longer than ``q_chunk``, whose
+    ``jax.checkpoint`` backward gives the same gradients."""
+    from repro.models.layers import attention_scores, flash_attention
+    B, Sq, Sk, Hkv, hd = 2, 16, 24, 2, 8
+    H = Hkv * rep
+    dt = jnp.dtype(dtype)
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2)
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), dt)
+
+    q, k, v = arr(B, Sq, H, hd), arr(B, Sk, Hkv, hd), arr(B, Sk, Hkv, hd)
+    # rows at their own offsets when ragged, else one offset for the batch
+    start = jnp.asarray([8, 3] if ragged else [8, 8])
+    qpos = start[:, None] + jnp.arange(Sq)[None]
+    kpos = jnp.broadcast_to(jnp.arange(Sk)[None], (B, Sk))
+    kv_valid = kpos < (start + Sq)[:, None]
+    cases = [dict(window=0), dict(window=5), dict(window=0, kv_valid=kv_valid)]
+
+    def close(a, b):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
+
+    for kw in cases:
+        want = _repeated_attention(q, k, v, qpos, kpos, ragged=ragged, **kw)
+        close(attention_scores(q, k, v, qpos, kpos, ragged=ragged, **kw), want)
+        close(flash_attention(q, k, v, qpos, kpos, q_chunk=8, ragged=ragged,
+                              **kw), want)
+    if dtype == "float32":
+        def loss(fn, **kw):
+            return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v, qpos, kpos, 5,
+                                                      ragged=ragged, **kw)))
+        got = jax.grad(loss(flash_attention, q_chunk=8), (0, 1, 2))(q, k, v)
+        want = jax.grad(loss(_repeated_attention), (0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            close(g, w)
+
+
+def _cell_attention_shapes():
+    """(cell, heads, KV heads, head dim, shards, [(batch, query length,
+    keys)]) of every benchmark cell: its prefill and decode buckets."""
+    import json
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    confs = {c["name"]: c["file"] for c in bench["configs"]}
+    out = []
+    for w in bench["workloads"]:
+        with open(os.path.join(repo, "bench", "cells", w["name"] + ".json")) as f:
+            cell = json.load(f)
+        with open(os.path.join(repo, confs[w["config"]])) as f:
+            conf = json.load(f)
+        H, Hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
+        hd = conf.get("head_dim") or conf["hidden_size"] // H
+        mesh = cell.get("mesh", {})
+        shards = (mesh.get("data", 1), 1, mesh.get("model", 1))
+        L = cell["max_len"]
+        shapes = [(b, s, L) for b in cell["prefill_batch"]
+                  for s in cell["prefill_seq"]]
+        shapes += [(b, 1, L) for b in cell["decode_batch"]]
+        out.append((w["name"], H, Hkv, hd, shards, shapes))
+    return out
+
+
+@pytest.mark.parametrize("cell", _cell_attention_shapes(), ids=lambda c: c[0])
+def test_grouped_attention_keeps_the_plain_gemm_route(cell, monkeypatch):
+    """On the TPU v5e profile, the attention contractions of every benchmark
+    cell's buckets stay plain ``lax.dot_general`` now that they are grouped
+    (B·Hkv groups of rep·Sq query rows), as they were repeated (B·H groups
+    of Sq rows): the Decision Module picks no LCMA for either."""
+    import repro.api as falcon
+    from repro.core import engine
+    from repro.core.falcon_gemm import FalconConfig, plan_batched
+    from repro.models.layers import flash_attention
+
+    name, H, Hkv, hd, shards, shapes = cell
+    cfg = FalconConfig(hardware="tpu_v5e", shards=shards)
+    rep = H // Hkv
+    routes = []
+    route = engine._route_grouped
+
+    def spy(G, M_, K, N, cfg_, dtype, shared_b):
+        use_core, d = route(G, M_, K, N, cfg_, dtype, shared_b)
+        routes.append(((G, M_, K, N), d.use_lcma))
+        return use_core, d
+
+    monkeypatch.setattr(engine, "_route_grouped", spy)
+    bf16 = jnp.bfloat16
+    for B, S, L in shapes:
+        sds = jax.ShapeDtypeStruct
+        args = (sds((B, S, H, hd), bf16), sds((B, L, Hkv, hd), bf16),
+                sds((B, L, Hkv, hd), bf16), sds((B, S), jnp.int32),
+                sds((B, L), jnp.int32))
+        routes.clear()
+        with falcon.use(cfg):
+            jax.eval_shape(lambda q, k, v, qp, kp: flash_attention(
+                q, k, v, qp, kp, 0, kv_valid=kp < L, ragged=True), *args)
+        sq = min(S, 512)                     # flash_attention's query chunk
+        grouped = [(B * Hkv, rep * sq, hd, L), (B * Hkv, rep * sq, L, hd)]
+        assert routes == [(s, False) for s in grouped], (name, B, S, routes)
+        for G, M_, K, N in ((B * H, sq, hd, L), (B * H, sq, L, hd)):
+            assert not plan_batched(G, M_, K, N, cfg, "bfloat16").use_lcma

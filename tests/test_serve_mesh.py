@@ -10,7 +10,10 @@ decode step gathers no cache rows. A model with 2 KV heads, which the
 4-way axis does not divide, keeps a replicated cache and serves the same
 way, speculative rounds (the self-draft's own cache) included. An engine
 that serves unwarmed counts its live compiles, the counter's positive
-control. All of it runs in one child process, since the devices are
+control. Attention groups each KV head's query heads where the axis divides
+the KV heads (16 query heads over 4 KV heads: no all-gather, the
+one-device result) and repeats K/V up to the query heads where it does not
+(2 KV heads), which ``engine.summary()["attn_kv_layout"]`` reports. All of it runs in one child process, since the devices are
 simulated only where ``XLA_FLAGS`` is set before JAX starts.
 """
 import json
@@ -54,9 +57,9 @@ def cache_all_gathers(eng):
     return re.findall(r"= \\w+\\[([\\d,]*)\\]\\S* all-gather(?:-start)?\\(", txt)
 
 
-def serve(num_kv_heads):
+def serve(num_kv_heads, num_heads=4):
     cfg = dataclasses.replace(smoke_config("granite_3_2b"),
-                              num_kv_heads=num_kv_heads)
+                              num_heads=num_heads, num_kv_heads=num_kv_heads)
     eng = ServeEngine(cfg, max_slots=4, max_prompt_len=16, max_new_tokens=4,
                       record_logits=True, seed=0, mesh_shape=MESH,
                       prefill_chunk=8, prefix_cache=True)
@@ -85,6 +88,7 @@ def serve(num_kv_heads):
             "prefix_hits": st["prefix_hits"],
             "kv_eager_writes": st["kv_eager_writes"],
             "padded_decode": st["decode_padded_tokens"] > st["decode_real_rows"],
+            "attn_kv_layout": st["attn_kv_layout"],
             "all_gathers": cache_all_gathers(eng)}}
 
 
@@ -117,7 +121,45 @@ def unwarmed():
             "bucket_misses": st["bucket_misses"]}}
 
 
+def attention(num_kv_heads):
+    # 16 query heads over a 4-way model axis, as the engine shards them;
+    # K/V by KV head where the axis divides them, else replicated
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import compat
+    from repro.models.layers import flash_attention
+    from repro.parallel.sharding import kv_layout
+    mesh = compat.make_mesh((1, 4), ("data", "model"))
+    rng = np.random.default_rng(3)
+    B, S, L, H, hd = 2, 8, 32, 16, 8
+    q = rng.standard_normal((B, S, H, hd)).astype(np.float32)
+    k, v = (rng.standard_normal((B, L, num_kv_heads, hd)).astype(np.float32)
+            for _ in range(2))
+    start = np.array([3, 11])
+    qpos = start[:, None] + np.arange(S)
+    kpos = np.broadcast_to(np.arange(L), (B, L))
+    valid = kpos < (start + S)[:, None]
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, qpos, kpos, 0, kv_valid=valid,
+                               q_chunk=4, ragged=True)
+
+    want = np.asarray(jax.jit(fn)(q, k, v))
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    kv = heads if num_kv_heads % 4 == 0 else NamedSharding(mesh, P())
+    with jax.set_mesh(mesh):
+        layout = kv_layout(H, num_kv_heads)
+        step = jax.jit(fn, in_shardings=(heads, kv, kv))
+        got = np.asarray(step(q, k, v))
+        txt = step.lower(q, k, v).compile().as_text()
+    return {{"layout": layout,
+            "match": bool(np.allclose(got, want, rtol=1e-5, atol=1e-5)),
+            "all_gathers": len(re.findall(r" all-gather(?:-start)?\\(", txt))}}
+
+
 print("RESULT", json.dumps({{"hkv4": serve(4), "hkv2": serve(2),
+                            "gqa": serve(4, num_heads=16),
+                            "attention_hkv4": attention(4),
+                            "attention_hkv2": attention(2),
                             "speculate": speculate(4),
                             "speculate_hkv2": speculate(2),
                             "unwarmed": unwarmed()}}))
@@ -131,7 +173,7 @@ def served():
     return json.loads(line[len("RESULT "):])
 
 
-CASES = ["hkv4", "hkv2", "speculate", "speculate_hkv2"]
+CASES = ["hkv4", "hkv2", "gqa", "speculate", "speculate_hkv2"]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -148,6 +190,7 @@ def test_each_path_is_exercised(served, case):
 
 @pytest.mark.parametrize("case,spec", [
     ("hkv4", "PartitionSpec(None, None, None, 'model', None)"),
+    ("gqa", "PartitionSpec(None, None, None, 'model', None)"),
     # 2 KV heads on a 4-way axis: replicated, as the parameter table does
     ("hkv2", "PartitionSpec(None, None, None, None, None)")])
 def test_kv_leaves_shard_by_kv_head_and_are_donated(served, case, spec):
@@ -173,3 +216,25 @@ def test_the_decode_step_gathers_no_cache_rows(served):
     gathers = served["hkv4"]["all_gathers"]
     assert gathers
     assert all(len(s.split(",")) < 5 for s in gathers), gathers
+
+
+@pytest.mark.parametrize("case,layout", [
+    ("hkv4", "grouped"), ("gqa", "grouped"),
+    # 2 KV heads on a 4-way axis: K/V repeated up to the query heads
+    ("hkv2", "repeat")])
+def test_the_engine_reports_how_attention_meets_the_kv_heads(served, case,
+                                                             layout):
+    assert served[case]["attn_kv_layout"] == layout
+
+
+@pytest.mark.parametrize("case,layout", [("attention_hkv4", "grouped"),
+                                         ("attention_hkv2", "repeat")])
+def test_attention_groups_query_heads_where_the_axis_divides_kv_heads(
+        served, case, layout):
+    """Both layouts give the one-device result; the grouped one keeps each
+    device's query group beside its KV head, so nothing is gathered."""
+    got = served[case]
+    assert got["layout"] == layout
+    assert got["match"] is True
+    if layout == "grouped":
+        assert got["all_gathers"] == 0
